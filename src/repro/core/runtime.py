@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
@@ -28,7 +29,10 @@ from repro.core import memory as _memory
 from repro.obs import profile as _profile
 import repro.core.targets  # noqa: F401  (register all variants)
 
-__all__ = ["DeviceRuntime", "runtime", "kernel_call"]
+__all__ = ["DeviceRuntime", "runtime", "kernel_call", "compiled_kernels"]
+
+_TPU_CUSTOM_CALL = re.compile(
+    r"%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,9 +118,10 @@ def kernel_call(kernel_fn, *, out_shape, grid=None, in_specs=None,
     """``pallas_call`` with the target decided by the runtime.
 
     The single entry point kernels launch through — the analogue of the
-    kernel-launch glue the device runtime provides.  On the ``generic``
-    target callers should not reach this (ops.py dispatches to ref.py);
-    calling it anyway falls back to interpret mode so behavior is total.
+    kernel-launch glue the device runtime provides.  The ``generic``
+    target has no Pallas lowering (ops dispatch to their ``ref.py``), so
+    reaching this there raises instead of silently interpreting: a
+    kernel that was meant to compile must never quietly run elsewhere.
 
     ``num_scalar_prefetch``: the leading N operands are small integer
     control arrays (block tables, lengths) made available *before* the
@@ -127,11 +132,17 @@ def kernel_call(kernel_fn, *, out_shape, grid=None, in_specs=None,
     in the common part of the runtime.
     """
     rt = rt or runtime()
+    if not rt.use_pallas:
+        raise RuntimeError(
+            f"kernel_call({name or getattr(kernel_fn, '__name__', 'kernel')}) "
+            f"on the {rt.arch!r} target, which has no Pallas lowering; "
+            f"dispatch to the reference there, or select the 'interpret' "
+            f"target to run kernels in the CPU interpreter")
     params = rt.compiler_params(dimension_semantics, vmem_limit_bytes)
     pk = dict(kwargs)
     if params is not None:
         pk["compiler_params"] = params
-    interpret = rt.interpret or not rt.use_pallas
+    interpret = rt.interpret
     if num_scalar_prefetch:
         from jax.experimental.pallas import tpu as pltpu
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -167,3 +178,11 @@ def kernel_call(kernel_fn, *, out_shape, grid=None, in_specs=None,
         label = name or getattr(kernel_fn, "__name__", "kernel")
         return _profile.wrap(f"kernel_call.{label}", call)
     return call
+
+
+def compiled_kernels(hlo_text: str) -> set:
+    """Names of the Mosaic-compiled kernels in a compiled module's text
+    (``jit(f).lower(...).compile().as_text()``): each ``kernel_call``
+    that reached the chip's compiler is a ``tpu_custom_call`` named
+    after its ``name=``.  Empty for interpret or generic lowerings."""
+    return set(_TPU_CUSTOM_CALL.findall(hlo_text))

@@ -42,8 +42,3 @@ def _compiler_params_tpu(dimension_semantics=None, vmem_limit_bytes=None):
     if vmem_limit_bytes is not None:
         kw["vmem_limit_bytes"] = int(vmem_limit_bytes)
     return pltpu.CompilerParams(**kw)
-
-
-@declare_variant(I.memory_space_any, match=match(device=arch("tpu")))
-def _memory_space_any_tpu():
-    return pltpu.TPUMemorySpace.ANY

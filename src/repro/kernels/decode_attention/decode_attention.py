@@ -27,13 +27,6 @@ LANES = 128
 SUBLANES = 8
 
 
-def _smem_space(rt: DeviceRuntime):
-    """Scalar control data lives in SMEM (the runtime's alloc_scalar
-    space); interpret mode honors the same descriptor."""
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.TPUMemorySpace.SMEM
-
-
 def flash_decode_step(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
                       acc_ref, m_ref, l_ref, *, rt: DeviceRuntime,
                       scale: float, window: Optional[int],
@@ -106,13 +99,14 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
                    acc_ref, m_ref, l_ref, *, rt: DeviceRuntime, scale: float,
                    window: Optional[int], softcap: Optional[float],
                    block_kv: int, kv_offset: int):
+    ib = rt.team_id(0)
     ik = rt.team_id(2)
     nk = rt.num_teams(2)
     flash_decode_step(
         q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
         acc_ref, m_ref, l_ref, rt=rt, scale=scale, window=window,
         softcap=softcap, k_start=kv_offset + ik * block_kv,
-        length=len_ref[0], ik=ik, nk=nk)
+        length=len_ref[ib], ik=ik, nk=nk)
 
 
 def decode_attention_fwd(q, k_cache, v_cache, lengths, *,
@@ -148,6 +142,16 @@ def decode_attention_fwd(q, k_cache, v_cache, lengths, *,
         _decode_kernel, rt=rt, scale=scale, window=window, softcap=softcap,
         block_kv=block_kv, kv_offset=kv_offset)
 
+    def q_map(ib, ih, ik, len_ref):
+        del ik, len_ref
+        return (ib, ih, 0, 0)
+
+    def kv_map(ib, ih, ik, len_ref):
+        del len_ref
+        return (ib, ih, ik, 0)
+
+    # lengths ride as a scalar-prefetch operand (SMEM, whole array): the
+    # compiler refuses a (1,) SMEM block of a (B,) array
     grid = (b, hkv, nk)
     acc, m, l = kernel_call(
         kern,
@@ -157,17 +161,16 @@ def decode_attention_fwd(q, k_cache, v_cache, lengths, *,
             jax.ShapeDtypeStruct((b, hkv, g8, LANES), jnp.float32),
         ),
         grid=grid,
+        num_scalar_prefetch=1,
         in_specs=[
-            pl.BlockSpec((1,), lambda ib, ih, ik: (ib,),
-                         memory_space=_smem_space(rt)),
-            pl.BlockSpec((1, 1, g8, d), lambda ib, ih, ik: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, 1, block_kv, d), lambda ib, ih, ik: (ib, ih, ik, 0)),
-            pl.BlockSpec((1, 1, block_kv, dv), lambda ib, ih, ik: (ib, ih, ik, 0)),
+            pl.BlockSpec((1, 1, g8, d), q_map),
+            pl.BlockSpec((1, 1, block_kv, d), kv_map),
+            pl.BlockSpec((1, 1, block_kv, dv), kv_map),
         ],
         out_specs=(
-            pl.BlockSpec((1, 1, g8, dv), lambda ib, ih, ik: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, 1, g8, LANES), lambda ib, ih, ik: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, 1, g8, LANES), lambda ib, ih, ik: (ib, ih, 0, 0)),
+            pl.BlockSpec((1, 1, g8, dv), q_map),
+            pl.BlockSpec((1, 1, g8, LANES), q_map),
+            pl.BlockSpec((1, 1, g8, LANES), q_map),
         ),
         scratch_shapes=[
             rt.alloc_shared((g8, dv), jnp.float32),

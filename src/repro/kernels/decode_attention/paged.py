@@ -30,8 +30,9 @@ never span two non-contiguous pages.
 With ``k_scales``/``v_scales`` (per-page-per-head f32 scale pools
 ``(Hkv, P)``, repro.quant) the same launch also serves the *quantized*
 pools: the scale block for a grid step rides the identical block-table
-index map as its KV block (a ``(1, 1)`` BlockSpec), and the dequant
-fuses into ``flash_decode_step`` as one scalar multiply after the DMA.
+index map as its KV block (a ``(1, 1, 1, 1)`` tile, ``scale_tiles``),
+and the dequant fuses into ``flash_decode_step`` as one scalar multiply
+after the DMA.
 ``quant.py`` wraps this as the ``quant_paged_decode_attention`` op.
 """
 from __future__ import annotations
@@ -56,7 +57,7 @@ def _paged_decode_kernel(*refs, rt: DeviceRuntime, scale: float,
     _, len_ref, q_ref, k_ref, v_ref = refs[:5]   # bt consumed by maps
     if quantized:
         ks_ref, vs_ref = refs[5:7]
-        k_scale, v_scale = ks_ref[0, 0], vs_ref[0, 0]
+        k_scale, v_scale = ks_ref[0, 0], vs_ref[0, 0]   # (1, 1): broadcasts
         rest = refs[7:]
     else:
         k_scale = v_scale = None
@@ -103,6 +104,16 @@ def repage_scales(scales, page_size: int, ps_phys: int):
     r = ps_phys // page_size
     h, p = scales.shape
     return jnp.repeat(scales, r, axis=1).reshape(h, p * r)
+
+
+def scale_tiles(scales):
+    """``(H, P)`` scale pool as ``(H, P, 1, 1)``: a grid step's scale
+    then rides a ``(1, 1, 1, 1)`` VMEM block whose last two dims equal
+    the array's, which Mosaic accepts (a ``(1, 1)`` block of ``(H, P)``
+    is refused, in VMEM and SMEM alike).  A free reshape.  Prefetching
+    the pools whole into SMEM (1 MiB on v5e) would instead cap two f32
+    pools of 8 heads near 16k pages."""
+    return scales.reshape(*scales.shape, 1, 1)
 
 
 def paged_decode_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
@@ -167,7 +178,7 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
 
     def sc_map(ib, ih, ik, bt_ref, len_ref):
         del len_ref
-        return (ih, bt_ref[ib, ik // spp])
+        return (ih, bt_ref[ib, ik // spp], 0, 0)
 
     def q_map(ib, ih, ik, bt_ref, len_ref):
         del ik, bt_ref, len_ref
@@ -181,8 +192,8 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
     operands = [qg, k_pages, v_pages]
     if quantized:
         # scale blocks ride the same block-table gather as the KV blocks
-        in_specs += [pl.BlockSpec((1, 1), sc_map), pl.BlockSpec((1, 1), sc_map)]
-        operands += [k_scales, v_scales]
+        in_specs += [pl.BlockSpec((1, 1, 1, 1), sc_map)] * 2
+        operands += [scale_tiles(k_scales), scale_tiles(v_scales)]
 
     grid = (b, hkv, nk)
     acc, m, l = kernel_call(
@@ -232,7 +243,7 @@ def _window_paged_decode_kernel(*refs, rt: DeviceRuntime, scale: float,
     _, len_ref, q_ref, k_ref, v_ref = refs[:5]
     if quantized:
         ks_ref, vs_ref = refs[5:7]
-        k_scale, v_scale = ks_ref[0, 0], vs_ref[0, 0]
+        k_scale, v_scale = ks_ref[0, 0], vs_ref[0, 0]   # (1, 1): broadcasts
         rest = refs[7:]
     else:
         k_scale = v_scale = None
@@ -325,7 +336,7 @@ def window_paged_decode_attention_fwd(q, k_pages, v_pages, block_tables,
         return (ih, bt_ref[ib, _col(ib, ik, len_ref)], ik % spp, 0)
 
     def sc_map(ib, ih, ik, bt_ref, len_ref):
-        return (ih, bt_ref[ib, _col(ib, ik, len_ref)])
+        return (ih, bt_ref[ib, _col(ib, ik, len_ref)], 0, 0)
 
     def q_map(ib, ih, ik, bt_ref, len_ref):
         del ik, bt_ref, len_ref
@@ -338,8 +349,8 @@ def window_paged_decode_attention_fwd(q, k_pages, v_pages, block_tables,
     ]
     operands = [qg, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1), sc_map), pl.BlockSpec((1, 1), sc_map)]
-        operands += [k_scales, v_scales]
+        in_specs += [pl.BlockSpec((1, 1, 1, 1), sc_map)] * 2
+        operands += [scale_tiles(k_scales), scale_tiles(v_scales)]
 
     grid = (b, hkv, nk)
     acc, m, l = kernel_call(

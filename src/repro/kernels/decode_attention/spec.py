@@ -42,7 +42,8 @@ from jax.experimental import pallas as pl
 from repro.core.runtime import DeviceRuntime, kernel_call
 from repro.kernels.decode_attention.decode_attention import (
     LANES, SUBLANES, flash_decode_step)
-from repro.kernels.decode_attention.paged import repage, repage_scales
+from repro.kernels.decode_attention.paged import (
+    repage, repage_scales, scale_tiles)
 
 
 def _spec_paged_decode_kernel(*refs, rt: DeviceRuntime, scale: float,
@@ -55,7 +56,7 @@ def _spec_paged_decode_kernel(*refs, rt: DeviceRuntime, scale: float,
     _, len_ref, q_ref, k_ref, v_ref = refs[:5]   # bt consumed by maps
     if quantized:
         ks_ref, vs_ref = refs[5:7]
-        k_scale, v_scale = ks_ref[0, 0], vs_ref[0, 0]
+        k_scale, v_scale = ks_ref[0, 0], vs_ref[0, 0]   # (1, 1): broadcasts
         rest = refs[7:]
     else:
         k_scale = v_scale = None
@@ -140,7 +141,7 @@ def spec_paged_decode_attention_fwd(q, k_pages, v_pages, block_tables,
 
     def sc_map(ib, ih, ik, bt_ref, len_ref):
         del len_ref
-        return (ih, bt_ref[ib, ik // spp])
+        return (ih, bt_ref[ib, ik // spp], 0, 0)
 
     def q_map(ib, ih, ik, bt_ref, len_ref):
         del ik, bt_ref, len_ref
@@ -153,8 +154,8 @@ def spec_paged_decode_attention_fwd(q, k_pages, v_pages, block_tables,
     ]
     operands = [qg, k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1), sc_map), pl.BlockSpec((1, 1), sc_map)]
-        operands += [k_scales, v_scales]
+        in_specs += [pl.BlockSpec((1, 1, 1, 1), sc_map)] * 2
+        operands += [scale_tiles(k_scales), scale_tiles(v_scales)]
 
     grid = (b, hkv, nk)
     acc, m, l = kernel_call(

@@ -4,9 +4,10 @@ Capacity-layout MoE expert matmul: tokens are pre-gathered into dense
 (E, C, K) per-expert buffers (repro.models.moe does the all_to_all),
 and each expert's (C, K) @ (K, N) runs as a blocked MXU matmul with a
 K-sequential accumulator in shared VMEM.  ``group_sizes`` rides in SMEM
-(scalar memory) and masks both compute (fully-empty blocks are skipped —
-the worksharing analogue of the paper's dynamic loop scheduling) and the
-padded capacity rows at writeback.
+(scalar memory, as a scalar-prefetch operand) and masks both compute
+(fully-empty blocks are skipped — the worksharing analogue of the
+paper's dynamic loop scheduling) and the padded capacity rows at
+writeback.
 """
 from __future__ import annotations
 
@@ -15,7 +16,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.runtime import DeviceRuntime, kernel_call
 
@@ -25,7 +25,7 @@ def _gmm_kernel(gs_ref, lhs_ref, rhs_ref, o_ref, acc_ref, *,
     e = rt.team_id(0)
     ic = rt.team_id(1)
     ik = rt.team_id(3)
-    size = gs_ref[0]
+    size = gs_ref[e]
 
     @rt.when(ik == 0)
     def _init():
@@ -56,20 +56,21 @@ def gmm_fwd(lhs, rhs, group_sizes, *, block_c: int = 512, block_n: int = 512,
 
     kern = functools.partial(_gmm_kernel, rt=rt, block_c=block_c,
                              nk=pl.cdiv(k, block_k))
+    # group_sizes ride as a scalar-prefetch operand (SMEM, whole array):
+    # the compiler refuses a (1,) SMEM block of an (E,) array
     return kernel_call(
         kern,
         out_shape=jax.ShapeDtypeStruct((e, c, n), lhs.dtype),
         grid=(e, pl.cdiv(c, block_c), pl.cdiv(n, block_n), pl.cdiv(k, block_k)),
+        num_scalar_prefetch=1,
         in_specs=[
-            pl.BlockSpec((1,), lambda ie, ic, jn, ik: (ie,),
-                         memory_space=pltpu.TPUMemorySpace.SMEM),
             pl.BlockSpec((1, block_c, block_k),
-                         lambda ie, ic, jn, ik: (ie, ic, ik)),
+                         lambda ie, ic, jn, ik, gs: (ie, ic, ik)),
             pl.BlockSpec((1, block_k, block_n),
-                         lambda ie, ic, jn, ik: (ie, ik, jn)),
+                         lambda ie, ic, jn, ik, gs: (ie, ik, jn)),
         ],
         out_specs=pl.BlockSpec((1, block_c, block_n),
-                               lambda ie, ic, jn, ik: (ie, ic, jn)),
+                               lambda ie, ic, jn, ik, gs: (ie, ic, jn)),
         scratch_shapes=[rt.alloc_shared((block_c, block_n), jnp.float32)],
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         name="portable_gmm",

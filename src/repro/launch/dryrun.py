@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
-# ^ MUST precede any jax import: jax locks the device count on first init.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST precede any jax import: jax locks the device count on first init,
+# and this CPU-only tool must never take a chip from another process.
 
 """Multi-pod dry-run: .lower().compile() every (arch x shape x mesh) cell.
 
@@ -35,7 +37,6 @@ import jax.numpy as jnp
 
 DEFAULT_OUT = os.path.join(os.path.dirname(__file__),
                            "../../../experiments/dryrun")
-CACHE_DIR = os.path.join(os.path.dirname(__file__), "../../../.jax_cache")
 
 # grad-accumulation microbatches per arch at train_4k (global batch 256):
 # sized so activation/dispatch transients fit v5e HBM (see EXPERIMENTS.md

@@ -134,6 +134,8 @@ def main():
     from repro.configs import get_config
     from repro.configs.smoke import smoke_config
     from repro.core import tuning
+    from repro.core.context import current_context
+    from repro.launch.compile_cache import place_compile_cache
     from repro.models.registry import build_model
     from repro.serve import Engine, FaultPlan, Request, ServeConfig, \
         ServeTelemetry
@@ -142,10 +144,11 @@ def main():
     # block_*=None then resolves to autotuned winners, no re-tuning.
     # (No-op if repro.kernels already auto-loaded them at import.)
     tuning.load_caches()
+    place_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = model.init_serving(jax.random.PRNGKey(0))
     sc = ServeConfig(slots=args.slots, cache_len=args.cache_len,
                      max_new_tokens=args.max_new,
                      temperature=args.temperature,
@@ -253,8 +256,12 @@ def main():
                       f, indent=1, sort_keys=True)
             f.write("\n")
 
+    dev = jax.devices()[0]
     print(json.dumps({
         "arch": args.arch, "paged": args.paged,
+        "device": {"platform": dev.platform, "device_kind": dev.device_kind,
+                   "count": len(jax.devices())},
+        "target_arch": current_context().arch,
         "kv_dtype": (engine.kv_spec.dtype if getattr(engine, "kv_spec", None)
                      else None),
         "requests": len(reqs),

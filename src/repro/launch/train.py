@@ -43,6 +43,7 @@ def main():
     from repro.configs.base import ShapeConfig
     from repro.configs.smoke import smoke_config
     from repro.core import tuning
+    from repro.launch.compile_cache import place_compile_cache
     from repro.launch.mesh import make_production_mesh
     from repro.train import TrainConfig, Trainer
 
@@ -50,6 +51,7 @@ def main():
     # block_*=None then resolves to autotuned winners, no re-tuning.
     # (No-op if repro.kernels already auto-loaded them at import.)
     tuning.load_caches()
+    place_compile_cache()
 
     if args.smoke:
         cfg = smoke_config(args.arch)

@@ -7,6 +7,7 @@ import dataclasses
 from typing import Any, Callable, Dict, Optional
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.configs.base import ModelConfig
@@ -19,6 +20,21 @@ class Model:
 
     def init(self, key) -> Dict[str, Any]:
         return T.init_params(key, self.cfg)
+
+    def init_serving(self, key) -> Dict[str, Any]:
+        """Serving weights: built under ``jax.jit`` and emitted in
+        ``cfg.dtype``, so no float32 master copy of the model is ever
+        materialized on the device (granite-8b is 33 GB in float32, 16.5
+        GB in bf16).  Every matmul already casts its weight at use, so
+        the logits match ``init``'s float32 tree cast the same way.
+        Training keeps ``init``."""
+        return jax.jit(self._init_in_dtype)(key)
+
+    def _init_in_dtype(self, key) -> Dict[str, Any]:
+        dt = jnp.dtype(self.cfg.dtype)
+        return jax.tree.map(
+            lambda x: x.astype(dt) if jnp.issubdtype(x.dtype, jnp.floating)
+            else x, T.init_params(key, self.cfg))
 
     def init_abstract(self, key=None) -> Dict[str, Any]:
         """ShapeDtypeStruct params (dry-run: no allocation)."""

@@ -426,12 +426,12 @@ def init_segment(key, cfg: ModelConfig, plan: SegmentPlan, *,
                  cross: bool = False):
     pos_params = []
     for i, (kind, is_moe) in enumerate(plan.block):
-        reps = []
-        for r in range(plan.reps):
-            k = jax.random.fold_in(key, r * len(plan.block) + i)
-            reps.append(init_layer(k, cfg, kind, is_moe, cross=cross))
-        pos_params.append(jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *reps))
+        # one vmapped init over the reps' keys: the same values as
+        # stacking per-rep inits, but traced (and compiled) once
+        keys = jnp.stack([jax.random.fold_in(key, r * len(plan.block) + i)
+                          for r in range(plan.reps)])
+        pos_params.append(jax.vmap(
+            lambda k: init_layer(k, cfg, kind, is_moe, cross=cross))(keys))
     return tuple(pos_params)
 
 

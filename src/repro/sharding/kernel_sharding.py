@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import shard_map
+from jax import shard_map
 from repro.core.runtime import runtime
 from repro.kernels.decode_attention.ops import (
     decode_attention, paged_decode_attention, quant_paged_decode_attention,

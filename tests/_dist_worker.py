@@ -13,18 +13,15 @@ os.environ.setdefault("XLA_FLAGS",
 import dataclasses  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-from repro.core.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs.base import ShapeConfig  # noqa: E402
 from repro.configs.smoke import smoke_config  # noqa: E402
 from repro.models import transformer as T  # noqa: E402
 from repro.models import moe as MOE  # noqa: E402
+from repro.launch.mesh import make_test_mesh as _mesh  # noqa: E402
 from repro.sharding import mesh_ctx  # noqa: E402
-
-
-def _mesh(shape, axes):
-    return jax.make_mesh(shape, axes)
 
 
 def _batch(cfg, b=4, s=32, seed=0):

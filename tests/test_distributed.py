@@ -20,6 +20,7 @@ CASES = ["forward_parity", "grad_parity_sp", "moe_a2a_parity",
 def _run(*cases):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"      # the child never contends for a chip
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     r = subprocess.run([sys.executable, WORKER, *cases],
                        capture_output=True, text=True, env=env, cwd=ROOT,

@@ -124,3 +124,16 @@ def test_atomic_inc_wraps_like_cuda_spec_sequence():
     r = FakeRef(jnp.int32(0))
     seq = [int(A.atomic_inc(r, 2)) for _ in range(6)]
     assert seq == [0, 1, 2, 0, 1, 2]
+
+
+def test_kernel_call_refuses_the_generic_target():
+    """The generic target has no Pallas lowering: reaching kernel_call
+    there is an error, never a silent run in the interpreter."""
+    def kern(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    with ctx.target("generic"):
+        with pytest.raises(RuntimeError, match="no Pallas lowering"):
+            kernel_call(kern,
+                        out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
+                        name="copy")
