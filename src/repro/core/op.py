@@ -77,7 +77,6 @@ import numpy as np
 from repro.core import context as ctx_mod
 from repro.core import tuning as tuning_mod
 from repro.core import variant as variant_mod
-from repro.obs import profile as _profile
 
 __all__ = ["DeviceOp", "device_op", "op_registry", "get_op", "all_ops",
            "compare_outputs"]
@@ -276,13 +275,6 @@ class DeviceOp:
 
     def __call__(self, *operands, **params):
         params = self.resolve_params(params)
-        if _profile.enabled():
-            # opt-in (REPRO_PROFILE=1) per-dispatch timer aggregated
-            # under device_op.<name>; kernel_call adds the inner timing
-            with _profile.timed(f"device_op.{self.name}"):
-                if not self.differentiable:
-                    return self.base(*operands, **params)
-                return _op_call(self, tuple(operands), _freeze(params))
         if not self.differentiable:
             return self.base(*operands, **params)
         return _op_call(self, tuple(operands), _freeze(params))

@@ -26,7 +26,6 @@ from repro.core import atomics as _atomics
 from repro.core import context as _context
 from repro.core import intrinsics as _intrinsics
 from repro.core import memory as _memory
-from repro.obs import profile as _profile
 import repro.core.targets  # noqa: F401  (register all variants)
 
 __all__ = ["DeviceRuntime", "runtime", "kernel_call", "compiled_kernels"]
@@ -172,11 +171,6 @@ def kernel_call(kernel_fn, *, out_shape, grid=None, in_specs=None,
             name=name,
             **pk,
         )
-    if _profile.enabled():
-        # opt-in (REPRO_PROFILE=1) dispatch timer, aggregated into the
-        # shared profile registry; the off path pays one bool check
-        label = name or getattr(kernel_fn, "__name__", "kernel")
-        return _profile.wrap(f"kernel_call.{label}", call)
     return call
 
 

@@ -155,10 +155,9 @@ class MetricsRegistry:
     """Named counters/gauges/histograms with get-or-create semantics.
 
     The serve engine's :meth:`~repro.serve.engine.Engine.stats` façade
-    reads from one of these; the kernel profiling hooks
-    (:mod:`repro.obs.profile`) aggregate into another.  A name maps to
-    exactly one metric type — re-requesting it with a different type
-    raises instead of silently shadowing.
+    reads from one of these; ``ServeTelemetry`` feeds another.  A name
+    maps to exactly one metric type — re-requesting it with a different
+    type raises instead of silently shadowing.
     """
 
     def __init__(self):

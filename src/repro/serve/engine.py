@@ -18,7 +18,11 @@ spec length, the bad-slot lane — are *piggybacked onto the existing
 step-result tuple*, so attaching a ``ServeTelemetry``
 (serve/telemetry.py) records the full per-request lifecycle trace and
 latency histograms without adding a single device sync; a regression
-test counts ``_device_get`` calls with telemetry on vs off.
+test counts ``_device_get`` calls with telemetry on vs off.  Host
+spans (``repro.engine.step``, ``.admit_group`` and their phases, see
+``obs.trace.span``) name each stretch of host work for a running
+profiler, and ``serve.compiles.<program>`` counts the calls that grew
+a jitted program's executable cache.
 
 Admission is batched: queued requests are grouped by prompt length and
 each group is prefilled in ONE compiled call (grouping by exact length
@@ -93,6 +97,7 @@ import numpy as np
 
 from repro.models.registry import Model
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import span
 from repro.serve import paging
 from repro.serve.faults import FAULT_KINDS, FaultPlan, corrupt_page, \
     nonfinite_pages
@@ -362,6 +367,8 @@ class Engine:
         m.counter("serve.spec_rejections")
         m.counter("serve.window_prefix_frees")
         m.gauge("serve.requeue_peak_depth")
+        for prog in ("prefill", "admit", "step", "spec"):
+            m.counter(f"serve.compiles.{prog}")
         self._admit_seq = np.zeros((slots,), np.int64)
         self._seq = 0
         self._key = jax.random.PRNGKey(sc.seed)
@@ -442,6 +449,18 @@ class Engine:
         if self.windowed:
             groups["window"] = self.allocator_w.brief()
         return groups
+
+    def _jit_call(self, prog: str, fn, sp, *args):
+        """Call the jitted ``fn``.  A call that grew its executable
+        cache compiled (or loaded) a program: it counts under
+        ``serve.compiles.<prog>`` and marks the span ``sp`` with
+        ``compiled=1``.  Reading the cache size syncs nothing."""
+        n = fn._cache_size()
+        out = fn(*args)
+        if fn._cache_size() > n:
+            self.metrics.counter(f"serve.compiles.{prog}").inc()
+            sp.set_metadata(compiled=1)
+        return out
 
     # -- jitted bodies ----------------------------------------------------
     def _resolve_page_size(self) -> int:
@@ -721,31 +740,35 @@ class Engine:
         Under the "priority" policy a waiting request whose class
         strictly exceeds an active slot's also evicts at admission
         time (see _priority_admission_preempt)."""
-        if self.paged and self.sc.preempt_policy == "priority":
-            self._priority_admission_preempt()
-        while self._free_slots() and (self.requeue or self.queue):
-            free = len(self._free_slots())
-            batch: List[Request] = self._take_waiting(free)
-            if not batch:
-                # everything waiting is backing off; idle steps keep
-                # ticking step_count, so the stamps always expire
-                return
-            groups: Dict[int, List[Request]] = {}
-            for r in batch:
-                # effective prompt: original tokens plus everything
-                # already generated (empty for fresh requests, the
-                # checkpoint for requeued ones)
-                groups.setdefault(len(r.tokens) + len(r.out), []).append(r)
-            admitted = 0
-            for plen, reqs in groups.items():
-                admitted += self._admit_group(reqs, plen)
-            # a request finishing *at* admission (EOS on the prefill
-            # sample, max_new=1) frees its slot immediately; loop so the
-            # queue can backfill it this same scheduling round.  Zero
-            # admissions means the page pool is out of capacity for
-            # everything queued — stop; frees will unblock it later.
-            if admitted == 0:
-                return
+        if not (self.requeue or self.queue):
+            return
+        with span("engine.admit"):
+            if self.paged and self.sc.preempt_policy == "priority":
+                self._priority_admission_preempt()
+            while self._free_slots() and (self.requeue or self.queue):
+                free = len(self._free_slots())
+                batch: List[Request] = self._take_waiting(free)
+                if not batch:
+                    # everything waiting is backing off; idle steps keep
+                    # ticking step_count, so the stamps always expire
+                    return
+                groups: Dict[int, List[Request]] = {}
+                for r in batch:
+                    # effective prompt: original tokens plus everything
+                    # already generated (empty for fresh requests, the
+                    # checkpoint for requeued ones)
+                    groups.setdefault(len(r.tokens) + len(r.out),
+                                      []).append(r)
+                admitted = 0
+                for plen, reqs in groups.items():
+                    admitted += self._admit_group(reqs, plen)
+                # a request finishing *at* admission (EOS on the prefill
+                # sample, max_new=1) frees its slot immediately; loop so
+                # the queue can backfill it this same scheduling round.
+                # Zero admissions means the page pool is out of capacity
+                # for everything queued — stop; frees will unblock it.
+                if admitted == 0:
+                    return
 
     def _requeue_front(self, reqs: List[Request]) -> None:
         """Push un-admittable requests back where they came from,
@@ -763,29 +786,47 @@ class Engine:
         back to their deque head (admission is the capacity check —
         allocation below can then never fail, so failure can't leak
         half a group)."""
-        if self.paged:
-            # +1: the first decode step writes at position plen, which
-            # may sit on the page after the prompt's last.  A requeued
-            # checkpoint at plen == cache_len finishes at admission and
-            # never decodes, so its need is capped at the cache.
-            need = paging.pages_per_slot(min(plen + 1, self.sc.cache_len),
-                                         self.page_size)
-            fit = self.allocator.available // max(need, 1)
-            if self.windowed:
-                need_w = len(paging.live_window_pages(
-                    min(plen + 1, self.sc.cache_len), self.window,
-                    self.page_size))
-                fit = min(fit,
-                          self.allocator_w.available // max(need_w, 1))
-            if fit < len(reqs):
-                self._requeue_front(reqs[fit:])
-                reqs = reqs[:fit]
-            if not reqs:
-                return 0
-        slots = self._free_slots()[:len(reqs)]
+        with span("engine.admit_group", k=len(reqs), plen=plen):
+            if self.paged:
+                # +1: the first decode step writes at position plen,
+                # which may sit on the page after the prompt's last.  A
+                # requeued checkpoint at plen == cache_len finishes at
+                # admission and never decodes, so its need is capped at
+                # the cache.
+                need = paging.pages_per_slot(
+                    min(plen + 1, self.sc.cache_len), self.page_size)
+                fit = self.allocator.available // max(need, 1)
+                if self.windowed:
+                    need_w = len(paging.live_window_pages(
+                        min(plen + 1, self.sc.cache_len), self.window,
+                        self.page_size))
+                    fit = min(fit,
+                              self.allocator_w.available // max(need_w, 1))
+                if fit < len(reqs):
+                    self._requeue_front(reqs[fit:])
+                    reqs = reqs[:fit]
+                if not reqs:
+                    return 0
+            slots = self._free_slots()[:len(reqs)]
+            with span("engine.admit.prefill") as sp:
+                toks = jnp.asarray([r.tokens + r.out for r in reqs],
+                                   jnp.int32)
+                logits, cache1 = self._jit_call("prefill", self._prefill,
+                                                sp, self.params, toks)
+                self._key, sub = jax.random.split(self._key)
+                first = self._sample(logits, sub)
+            with span("engine.admit.sync"):
+                first_h = np.asarray(_device_get(first))  # one sync/group
+            with span("engine.admit.scatter") as sp:
+                self._scatter_group(reqs, plen, slots, first_h, cache1, sp)
+        return len(reqs)
 
+    def _scatter_group(self, reqs: List[Request], plen: int,
+                       slots: List[int], first_h, cache1, sp) -> None:
+        """Give an admitted group its pages and history rows, write its
+        prefill cache and first tokens into the slots (one ``_admit_fn``
+        call), and take it into the host's slot bookkeeping."""
         k = len(reqs)
-        toks = jnp.asarray([r.tokens + r.out for r in reqs], jnp.int32)
         # token-history rows for the spec proposer: position p holds the
         # token cached at row p.  Host-built at the fixed width W so the
         # admit retrace stays keyed on group size only; the prefill
@@ -794,10 +835,6 @@ class Engine:
         hist_rows = np.zeros((k, self.sc.cache_len + 1), np.int32)
         for i, r in enumerate(reqs):
             hist_rows[i, :plen] = r.tokens + r.out
-        logits, cache1 = self._prefill(self.params, toks)
-        self._key, sub = jax.random.split(self._key)
-        first = self._sample(logits, sub)
-        first_h = np.asarray(_device_get(first))     # one sync per group
 
         page_rows = None
         page_rows_w = None
@@ -843,7 +880,8 @@ class Engine:
                                   np.int32)
 
         (self.caches, self.lengths, self.cur_tok, self.active_mask,
-         self.n_out, self.tok_hist, self.max_new_dev) = self._admit_fn(
+         self.n_out, self.tok_hist, self.max_new_dev) = self._jit_call(
+            "admit", self._admit_fn, sp,
             self.caches, self.lengths, self.cur_tok, self.active_mask,
             self.n_out, self.tok_hist, self.max_new_dev, cache1,
             jnp.asarray(first_h), jnp.asarray(slots, jnp.int32),
@@ -878,7 +916,6 @@ class Engine:
                 if tel is not None:
                     tel.on_finish(req, slot, self.step_count)
                 self._release(slot)
-        return k
 
     def _release(self, slot: int):
         """Return a slot (and its pages) to the pool."""
@@ -960,30 +997,31 @@ class Engine:
         parked exactly like a released slot's — active mask off, block
         table reset to the null page so the stale ``cur_tok`` keeps
         scattering its KV into trash until the slot is reused."""
-        req = self.active[slot]
-        eff = len(req.tokens) + len(req.out)
-        usable = self.allocator.usable
-        if paging.pages_per_slot(min(eff + 1, self.sc.cache_len),
-                                 self.page_size) > usable:
-            # the checkpoint could never be re-admitted: requeueing it
-            # would spin forever, so surface the sizing problem now
-            raise RuntimeError(
-                f"request {req.rid}: checkpoint of {eff} tokens needs "
-                f"more KV pages than the pool's usable capacity ({usable} "
-                f"x {self.page_size}); raise ServeConfig.total_pages")
-        req.preempts += 1
-        self.metrics.counter("serve.preemptions").inc()
-        self.metrics.counter(
-            f"serve.preemptions.{self.sc.preempt_policy}").inc()
-        self.requeue.append(req)
-        self.metrics.gauge("serve.requeue_peak_depth").set_max(
-            len(self.requeue))
-        if self.telemetry is not None:
-            self.telemetry.on_preempt(req, slot, self.step_count)
-        # park the device rows: the jitted step must stop advancing this
-        # slot *before* the next decode, not at its end like finish does
-        self.active_mask = self.active_mask.at[slot].set(False)
-        self._release(slot)
+        with span("engine.preempt", slot=slot):
+            req = self.active[slot]
+            eff = len(req.tokens) + len(req.out)
+            usable = self.allocator.usable
+            if paging.pages_per_slot(min(eff + 1, self.sc.cache_len),
+                                     self.page_size) > usable:
+                # the checkpoint could never be re-admitted: requeueing it
+                # would spin forever, so surface the sizing problem now
+                raise RuntimeError(
+                    f"request {req.rid}: checkpoint of {eff} tokens needs "
+                    f"more KV pages than the pool's usable capacity ({usable} "
+                    f"x {self.page_size}); raise ServeConfig.total_pages")
+            req.preempts += 1
+            self.metrics.counter("serve.preemptions").inc()
+            self.metrics.counter(
+                f"serve.preemptions.{self.sc.preempt_policy}").inc()
+            self.requeue.append(req)
+            self.metrics.gauge("serve.requeue_peak_depth").set_max(
+                len(self.requeue))
+            if self.telemetry is not None:
+                self.telemetry.on_preempt(req, slot, self.step_count)
+            # park the device rows: the jitted step must stop advancing this
+            # slot *before* the next decode, not at its end like finish does
+            self.active_mask = self.active_mask.at[slot].set(False)
+            self._release(slot)
 
     def _ensure_pages(self, horizon: int = 1):
         """Allocate the pages the next ``horizon`` tokens of each active
@@ -1232,69 +1270,83 @@ class Engine:
         single device_get lands inside the watchdog deadline; sentinel-
         flagged slots commit nothing and route through the recovery
         ladder instead."""
-        self.step_count += 1
-        self._admit()
-        if not self._active_h.any():
-            return False
-        nan_slots, stall = self._draw_faults()
-        if self.spec:
-            return self._spec_step(nan_slots, stall)
-        if self.paged:
-            self._ensure_pages()
-            if not self._active_h.any():   # alloc_fail took the last slot
-                return True
-            if self._bt_dirty:        # re-upload only when tables changed
-                self._bt_dev = jnp.asarray(self.block_tables)
-                self._bt_dirty = False
-            bt = self._bt_dev
-            if self.windowed:
-                if self._btw_dirty:
-                    self._btw_dev = jnp.asarray(self.block_tables_w)
-                    self._btw_dirty = False
-                bt = {"global": self._bt_dev, "window": self._btw_dev}
-        else:
-            bt = None
-        self._key, sub = jax.random.split(self._key)
-        eos = jnp.int32(self.sc.eos_id if self.sc.eos_id is not None else -1)
-        t0 = time.perf_counter()
-        (next_tok, new_lengths, new_active, new_n_out, done, bad, emitted,
-         new_caches) = self._step_fn(
-            self.params, self.caches, self.cur_tok, self.lengths,
-            self.active_mask, self.n_out, sub, eos, self.max_new_dev, bt,
-            self._nan_mask(nan_slots))
-        if stall:
-            time.sleep(stall)                       # injected device stall
-        # THE one sync/step — the emitted-token counter piggybacks here
-        nt, dn, bh, em = _device_get((next_tok, done, bad, emitted))
-        if self._watchdog_tripped(t0):
-            return True             # step discarded; active slots requeued
-        self.lengths, self.active_mask, self.n_out = \
-            new_lengths, new_active, new_n_out
-        self.caches = new_caches
-        self.cur_tok = next_tok
-        nt, dn, bh = np.asarray(nt), np.asarray(dn), np.asarray(bh)
-        tel = self.telemetry
-        n_bad = 0
-        for slot in np.nonzero(self._active_h)[0]:
-            slot = int(slot)
-            if bh[slot]:
-                n_bad += 1
-                self._handle_bad_slot(slot)
-                continue
-            req = self.active[slot]
-            req.out.append(int(nt[slot]))
-            self._len_h[slot] += 1
-            if tel is not None:
-                tel.on_tokens(req, slot, self.step_count, 1)
-            if dn[slot]:
-                req.done = True
+        with span("engine.step") as sp:
+            self.step_count += 1
+            self._admit()
+            if not self._active_h.any():
+                return False
+            sp.set_metadata(batch=int(self._active_h.sum()))
+            nan_slots, stall = self._draw_faults()
+            if self.spec:
+                return self._spec_step(nan_slots, stall)
+            return self._plain_step(nan_slots, stall)
+
+    def _plain_step(self, nan_slots: List[int], stall: float) -> bool:
+        """One plain decode step: ensure pages, run the jitted step, take
+        its one device_get, then commit each slot's token."""
+        with span("engine.step.pages"):
+            if self.paged:
+                self._ensure_pages()
+                if not self._active_h.any():   # alloc_fail took the last
+                    return True
+                if self._bt_dirty:   # re-upload only when tables changed
+                    self._bt_dev = jnp.asarray(self.block_tables)
+                    self._bt_dirty = False
+                bt = self._bt_dev
+                if self.windowed:
+                    if self._btw_dirty:
+                        self._btw_dev = jnp.asarray(self.block_tables_w)
+                        self._btw_dirty = False
+                    bt = {"global": self._bt_dev, "window": self._btw_dev}
+            else:
+                bt = None
+        with span("engine.step.dispatch") as sp:
+            self._key, sub = jax.random.split(self._key)
+            eos = jnp.int32(self.sc.eos_id if self.sc.eos_id is not None
+                            else -1)
+            t0 = time.perf_counter()
+            (next_tok, new_lengths, new_active, new_n_out, done, bad,
+             emitted, new_caches) = self._jit_call(
+                "step", self._step_fn, sp,
+                self.params, self.caches, self.cur_tok, self.lengths,
+                self.active_mask, self.n_out, sub, eos, self.max_new_dev,
+                bt, self._nan_mask(nan_slots))
+        with span("engine.step.sync"):
+            if stall:
+                time.sleep(stall)                   # injected device stall
+            # THE one sync/step — the emitted-token counter piggybacks here
+            nt, dn, bh, em = _device_get((next_tok, done, bad, emitted))
+        with span("engine.step.commit"):
+            if self._watchdog_tripped(t0):
+                return True         # step discarded; active slots requeued
+            self.lengths, self.active_mask, self.n_out = \
+                new_lengths, new_active, new_n_out
+            self.caches = new_caches
+            self.cur_tok = next_tok
+            nt, dn, bh = np.asarray(nt), np.asarray(dn), np.asarray(bh)
+            tel = self.telemetry
+            n_bad = 0
+            for slot in np.nonzero(self._active_h)[0]:
+                slot = int(slot)
+                if bh[slot]:
+                    n_bad += 1
+                    self._handle_bad_slot(slot)
+                    continue
+                req = self.active[slot]
+                req.out.append(int(nt[slot]))
+                self._len_h[slot] += 1
                 if tel is not None:
-                    tel.on_finish(req, slot, self.step_count)
-                self._release(slot)
-        if tel is not None:
-            tel.on_step(self.step_count, emitted=int(em), bad_slots=n_bad,
-                        pools=(self._pool_pressure_brief()
-                               if self.paged else None))
+                    tel.on_tokens(req, slot, self.step_count, 1)
+                if dn[slot]:
+                    req.done = True
+                    if tel is not None:
+                        tel.on_finish(req, slot, self.step_count)
+                    self._release(slot)
+            if tel is not None:
+                tel.on_step(self.step_count, emitted=int(em),
+                            bad_slots=n_bad,
+                            pools=(self._pool_pressure_brief()
+                                   if self.paged else None))
         return True
 
     def _spec_step(self, nan_slots: List[int], stall: float) -> bool:
@@ -1308,75 +1360,81 @@ class Engine:
         a sentinel-flagged slot skips commit *and* rollback — release
         reclaims its whole ensured row."""
         k1 = self.sc.spec_k + 1
-        self._ensure_pages(horizon=k1)
-        if not self._active_h.any():       # alloc_fail took the last slot
-            return True
-        if self._bt_dirty:
-            self._bt_dev = jnp.asarray(self.block_tables)
-            self._bt_dirty = False
-        if self._spec_ok_dirty:
-            self._spec_ok_dev = jnp.asarray(self._spec_ok_h)
-            self._spec_ok_dirty = False
-        eos = jnp.int32(self.sc.eos_id if self.sc.eos_id is not None else -1)
-        t0 = time.perf_counter()
-        (y, n_emit, new_lengths, new_active, new_n_out, done, bad,
-         new_caches, new_hist, new_cur) = self._spec_fn(
-            self.params, self.caches, self.tok_hist, self.cur_tok,
-            self.lengths, self.active_mask, self.n_out, eos,
-            self.max_new_dev, self._bt_dev, self._nan_mask(nan_slots),
-            self._spec_ok_dev)
-        if stall:
-            time.sleep(stall)                       # injected device stall
-        yh, ne, dn, bh = _device_get((y, n_emit, done, bad))  # THE one sync
-        if self._watchdog_tripped(t0):
-            return True             # step discarded; active slots requeued
-        self.lengths, self.active_mask, self.n_out = \
-            new_lengths, new_active, new_n_out
-        self.caches, self.tok_hist, self.cur_tok = \
-            new_caches, new_hist, new_cur
-        yh, ne, dn, bh = (np.asarray(yh), np.asarray(ne), np.asarray(dn),
-                          np.asarray(bh))
-        self.metrics.counter("serve.spec_steps").inc()
-        tel = self.telemetry
-        n_bad = 0
-        accepted = 0
-        for slot in np.nonzero(self._active_h)[0]:
-            slot = int(slot)
-            if bh[slot]:
-                n_bad += 1
-                self._handle_bad_slot(slot)   # release reclaims the row
-                continue
-            req = self.active[slot]
-            m = int(ne[slot])
-            req.out.extend(int(t) for t in yh[slot, :m])
-            self._len_h[slot] += m
-            self.metrics.counter("serve.spec_emitted").inc(m)
-            accepted += m
-            if tel is not None and m > 0:
-                tel.on_tokens(req, slot, self.step_count, m)
-            if dn[slot]:
-                req.done = True
-                if tel is not None:
-                    tel.on_finish(req, slot, self.step_count)
-                self._release(slot)     # reclaims the whole row, tail incl.
-            else:
-                if m < k1:
-                    self.metrics.counter("serve.spec_rejections").inc()
-                # rollback: drop the rejected tail's pages; rejected rows
-                # inside kept pages sit past the new length and are
-                # masked by every later read
-                keep = paging.pages_per_slot(int(self._len_h[slot]),
-                                             self.page_size)
-                if paging.truncate_suffix(self.allocator,
-                                          self.block_tables[slot], keep,
-                                          int(self._ensured[slot])):
-                    self._bt_dirty = True
-        if tel is not None:
-            # ne rode the step's existing single device_get: the
-            # accepted spec length per slot IS the emitted count
-            tel.on_step(self.step_count, emitted=accepted,
-                        bad_slots=n_bad, accepted=accepted,
-                        pools=self._pool_pressure_brief())
+        with span("engine.step.pages"):
+            self._ensure_pages(horizon=k1)
+            if not self._active_h.any():   # alloc_fail took the last slot
+                return True
+            if self._bt_dirty:
+                self._bt_dev = jnp.asarray(self.block_tables)
+                self._bt_dirty = False
+            if self._spec_ok_dirty:
+                self._spec_ok_dev = jnp.asarray(self._spec_ok_h)
+                self._spec_ok_dirty = False
+        with span("engine.step.dispatch") as sp:
+            eos = jnp.int32(self.sc.eos_id if self.sc.eos_id is not None
+                            else -1)
+            t0 = time.perf_counter()
+            (y, n_emit, new_lengths, new_active, new_n_out, done, bad,
+             new_caches, new_hist, new_cur) = self._jit_call(
+                "spec", self._spec_fn, sp,
+                self.params, self.caches, self.tok_hist, self.cur_tok,
+                self.lengths, self.active_mask, self.n_out, eos,
+                self.max_new_dev, self._bt_dev, self._nan_mask(nan_slots),
+                self._spec_ok_dev)
+        with span("engine.step.sync"):
+            if stall:
+                time.sleep(stall)                   # injected device stall
+            yh, ne, dn, bh = _device_get((y, n_emit, done, bad))  # THE sync
+        with span("engine.step.commit"):
+            if self._watchdog_tripped(t0):
+                return True         # step discarded; active slots requeued
+            self.lengths, self.active_mask, self.n_out = \
+                new_lengths, new_active, new_n_out
+            self.caches, self.tok_hist, self.cur_tok = \
+                new_caches, new_hist, new_cur
+            yh, ne, dn, bh = (np.asarray(yh), np.asarray(ne), np.asarray(dn),
+                              np.asarray(bh))
+            self.metrics.counter("serve.spec_steps").inc()
+            tel = self.telemetry
+            n_bad = 0
+            accepted = 0
+            for slot in np.nonzero(self._active_h)[0]:
+                slot = int(slot)
+                if bh[slot]:
+                    n_bad += 1
+                    self._handle_bad_slot(slot)   # release reclaims the row
+                    continue
+                req = self.active[slot]
+                m = int(ne[slot])
+                req.out.extend(int(t) for t in yh[slot, :m])
+                self._len_h[slot] += m
+                self.metrics.counter("serve.spec_emitted").inc(m)
+                accepted += m
+                if tel is not None and m > 0:
+                    tel.on_tokens(req, slot, self.step_count, m)
+                if dn[slot]:
+                    req.done = True
+                    if tel is not None:
+                        tel.on_finish(req, slot, self.step_count)
+                    self._release(slot)   # reclaims the whole row, tail incl.
+                else:
+                    if m < k1:
+                        self.metrics.counter("serve.spec_rejections").inc()
+                    # rollback: drop the rejected tail's pages; rejected rows
+                    # inside kept pages sit past the new length and are
+                    # masked by every later read
+                    keep = paging.pages_per_slot(int(self._len_h[slot]),
+                                                 self.page_size)
+                    if paging.truncate_suffix(self.allocator,
+                                              self.block_tables[slot], keep,
+                                              int(self._ensured[slot])):
+                        self._bt_dirty = True
+            if tel is not None:
+                # ne rode the step's existing single device_get: the
+                # accepted spec length per slot IS the emitted count
+                tel.on_step(self.step_count, emitted=accepted,
+                            bad_slots=n_bad, accepted=accepted,
+                            pools=self._pool_pressure_brief())
         return True
 
     def run_to_completion(self, requests: List[Request],

@@ -2,8 +2,9 @@
 metrics primitives against the numpy reference, trace schema/lifecycle
 validation, the zero-extra-sync regression (telemetry must not change
 the engine's one-device_get-per-step contract, plain or speculative),
-the (step, wall-time) watchdog/recovery records in stats(), and the
-opt-in REPRO_PROFILE kernel hooks."""
+the (step, wall-time) watchdog/recovery records in stats(), the
+engine's host spans as a profiler records them, and the engine's
+compile counters."""
 import json
 
 import jax
@@ -12,7 +13,6 @@ import pytest
 
 from repro.configs.smoke import smoke_config
 from repro.models.registry import build_model
-from repro.obs import profile
 from repro.obs.metrics import Histogram, MetricsRegistry
 from repro.obs.trace import EVENT_KINDS, Trace
 from repro.serve import (Engine, FaultPlan, Request, ServeConfig,
@@ -293,30 +293,119 @@ def test_fault_plan_keeps_injection_log():
                for step, kind, _slot in plan.injection_log)
 
 
-# --------------------------------------------- REPRO_PROFILE hooks ----
+# ------------------------------------------------ engine host spans ----
 
-def test_profile_hooks_aggregate_device_op_timings():
-    """REPRO_PROFILE wraps device_op dispatch (core/op.py) and
-    kernel_call (core/runtime.py) with timers into one registry; off
-    by default so the hot path pays a single bool check."""
-    from repro.kernels import registry as R
+_STEP_SPANS = {"repro.engine.step", "repro.engine.step.pages",
+               "repro.engine.step.dispatch", "repro.engine.step.sync",
+               "repro.engine.step.commit"}
+_ADMIT_SPANS = {"repro.engine.admit", "repro.engine.admit_group",
+                "repro.engine.admit.prefill", "repro.engine.admit.sync",
+                "repro.engine.admit.scatter"}
 
-    op = next(o for o in R.all_ops() if o.name == "rmsnorm")
-    operands, params = op.example_inputs(jax.random.PRNGKey(0))
-    profile.reset()
-    was = profile.enabled()
+
+def _traced_spans(tmp_path, run):
+    """Run ``run()`` under the profiler and return the ``repro.*`` host
+    spans of its trace as (name, start_ns, end_ns, line, args)."""
+    jax.profiler.start_trace(str(tmp_path))
     try:
-        profile.enable(False)
-        op(*operands, **params)
-        assert profile.summary() == {"counters": {}, "gauges": {},
-                                     "histograms": {}}
-        profile.enable(True)
-        op(*operands, **params)
+        run()
     finally:
-        profile.enable(was)
-    snap = profile.summary()
-    assert snap["counters"]["device_op.rmsnorm.calls"] == 1
-    hist = snap["histograms"]["device_op.rmsnorm.s"]
-    assert hist["count"] == 1 and hist["p50"] > 0
-    profile.reset()
-    assert profile.summary()["counters"] == {}
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro."):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, line.name,
+                                dict(e.stats)))
+    return out
+
+
+def _inside(child, parents):
+    return any(p[3] == child[3] and p[1] <= child[1] and child[2] <= p[2]
+               for p in parents)
+
+
+@pytest.mark.parametrize("case", ["plain", "spec", "preempt"])
+def test_engine_spans_nest_and_count(tmp_path, monkeypatch, case):
+    """Under the profiler every engine phase the run reaches shows as a
+    ``repro.*`` host span: one ``engine.step`` per ``step()`` call, one
+    ``engine.admit_group`` per prompt-length group (with its k and plen),
+    each ``*.sync`` inside its parent, every step phase inside a step."""
+    kw = {"plain": dict(slots=4),
+          "spec": dict(slots=4, spec_mode="ngram", spec_k=2),
+          "preempt": dict(slots=2, page_size=8, total_pages=5,
+                          max_new_tokens=24)}[case]
+    eng = _engine(**kw)
+    reqs = ([Request(rid=i, tokens=[1 + i] * 6) for i in range(4)]
+            if case == "preempt" else
+            [Request(rid=0, tokens=[3, 5, 7, 11, 13]),
+             Request(rid=1, tokens=[2, 4, 6, 8, 10]),
+             Request(rid=2, tokens=[9, 8, 7, 6, 5, 4, 3])])
+    groups = []
+    admit_group = Engine._admit_group
+
+    def spy(self, reqs, plen):
+        groups.append({"k": len(reqs), "plen": plen})
+        return admit_group(self, reqs, plen)
+    monkeypatch.setattr(Engine, "_admit_group", spy)
+    calls = []
+
+    def run():
+        for r in reqs:
+            eng.submit(r)
+        while True:
+            calls.append(eng.step())
+            if not calls[-1] and not eng.queue and not eng.requeue:
+                break
+    spans = _traced_spans(tmp_path, run)
+    assert all(r.done for r in reqs)
+    names = {s[0] for s in spans}
+    want = _STEP_SPANS | _ADMIT_SPANS
+    if case == "preempt":
+        assert eng.preemptions > 0
+        want = want | {"repro.engine.preempt"}
+    assert names == want
+
+    steps = [s for s in spans if s[0] == "repro.engine.step"]
+    assert len(steps) == len(calls)
+    assert [s[4].get("batch", 0) > 0 for s in
+            sorted(steps, key=lambda s: s[1])] == calls
+    grp = [s for s in spans if s[0] == "repro.engine.admit_group"]
+    assert [s[4] for s in sorted(grp, key=lambda s: s[1])] == groups
+    if case == "plain":
+        assert groups == [{"k": 2, "plen": 5}, {"k": 1, "plen": 7}]
+    for s in spans:
+        if s[0] == "repro.engine.step.sync":
+            assert _inside(s, steps)
+        elif s[0] == "repro.engine.admit.sync":
+            assert _inside(s, grp)
+        if s[0] in _STEP_SPANS - {"repro.engine.step"}:
+            assert _inside(s, steps)
+    # the engine's programs compile on their first call only
+    disp = sorted((s for s in spans if s[0] == "repro.engine.step.dispatch"),
+                  key=lambda s: s[1])
+    assert disp[0][4] == {"compiled": 1}
+    assert all(s[4] == {} for s in disp[1:])
+
+
+def test_compile_counter_counts_new_prompt_lengths_once():
+    """``serve.compiles.prefill`` rises by one for a new prompt length
+    and not for a repeated one; the step compiles once."""
+    eng = _engine(slots=2)
+    c = eng.metrics.counter
+
+    def serve(plen, rid):
+        _drive(eng, [Request(rid=rid, tokens=[5] * plen)])
+        return {k: c(f"serve.compiles.{k}").value
+                for k in ("prefill", "admit", "step", "spec")}
+
+    assert serve(5, 0) == {"prefill": 1, "admit": 1, "step": 1, "spec": 0}
+    assert serve(5, 1) == {"prefill": 1, "admit": 1, "step": 1, "spec": 0}
+    assert serve(7, 2) == {"prefill": 2, "admit": 1, "step": 1, "spec": 0}
+    snap = eng.metrics.snapshot()["counters"]
+    assert snap["serve.compiles.prefill"] == 2
