@@ -27,6 +27,44 @@ LANES = 128
 SUBLANES = 8
 
 
+def online_softmax_update(q, k, v, acc_ref, m_ref, l_ref, *,
+                          rt: DeviceRuntime, window: Optional[int],
+                          softcap: Optional[float], k_start, horizon):
+    """Fold one KV block into the running (acc, m, l) accumulators.
+
+    The flash math every decode kernel shares.  ``q`` (…, G8, D) is
+    scaled f32, ``k``/``v`` (…, bkv, D|Dv) dequantized f32; the leading
+    axes, if any, are batch axes (the paged kernel passes all KV heads
+    of a slot at once), and the refs carry the same leading axes.
+    ``k_start`` is the global position of the block's first row and
+    ``horizon`` the valid prefix: a scalar, or a (G8, 1) per-row bound.
+    """
+    nd = q.ndim
+    batch = tuple(range(nd - 2))
+    s = jax.lax.dot_general(q, k, (((nd - 1,), (nd - 1,)), (batch, batch)),
+                            preferred_element_type=jnp.float32)  # (…, G8, bkv)
+    if softcap is not None:
+        s = softcap * jnp.tanh(s / softcap)
+    k_pos = k_start + rt.iota(s.shape, nd - 1)
+    mask = k_pos < horizon
+    if window is not None:
+        mask = jnp.logical_and(mask, (horizon - 1 - k_pos) < window)
+    s = jnp.where(mask, s, NEG_INF)
+
+    m_prev = m_ref[..., :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=nd - 1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
+    alpha = jnp.where(m_new > NEG_INF / 2, alpha, 0.0)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(
+        p, axis=nd - 1, keepdims=True) * jnp.ones_like(l_ref)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v, (((nd - 1,), (nd - 2,)), (batch, batch)),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new * jnp.ones_like(m_ref)
+
+
 def flash_decode_step(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
                       acc_ref, m_ref, l_ref, *, rt: DeviceRuntime,
                       scale: float, window: Optional[int],
@@ -34,10 +72,10 @@ def flash_decode_step(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
                       k_scale=None, v_scale=None, row_length=None):
     """One KV-block update of the online-softmax accumulation.
 
-    The shared body of the dense, paged, quantized-paged, and
-    speculative decode kernels: they differ only in how KV blocks reach
-    VMEM (contiguous BlockSpec walk vs. block-table gather) — the flash
-    math is target/layout common.  ``k_start`` is the global token
+    The shared body of the dense, windowed-paged and speculative decode
+    kernels: they differ only in how KV blocks reach VMEM (contiguous
+    BlockSpec walk vs. block-table gather); the flash math itself is
+    ``online_softmax_update``, which the paged kernel calls directly.  ``k_start`` is the global token
     position of this block's first row, ``length`` the valid prefix,
     ``ik``/``nk`` this step's position on the sequential KV grid axis
     (init on first, emit on last).  ``k_scale``/``v_scale`` are
@@ -63,30 +101,12 @@ def flash_decode_step(q_ref, k_ref, v_ref, o_ref, m_out_ref, l_out_ref,
             k = k * k_scale
         if v_scale is not None:
             v = v * v_scale
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G8, bkv)
-        if softcap is not None:
-            s = softcap * jnp.tanh(s / softcap)
-        k_pos = k_start + rt.iota(s.shape, 1)
         # per-row horizon when given ((G8,1) broadcasts against (G8,bkv));
         # scalar length otherwise — the single-query kernels' fast path
-        horizon = length if row_length is None else row_length
-        mask = k_pos < horizon
-        if window is not None:
-            mask = jnp.logical_and(mask, (horizon - 1 - k_pos) < window)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(m_new > NEG_INF / 2, p, 0.0)
-        alpha = jnp.where(m_new > NEG_INF / 2, alpha, 0.0)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(
-            p, axis=1, keepdims=True) * jnp.ones_like(l_ref)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new * jnp.ones_like(m_ref)
+        online_softmax_update(
+            q, k, v, acc_ref, m_ref, l_ref, rt=rt, window=window,
+            softcap=softcap, k_start=k_start,
+            horizon=length if row_length is None else row_length)
 
     @rt.when(ik == nk - 1)
     def _finalize():

@@ -3,11 +3,11 @@
 One launch serves both pool dtypes: the block-table gather, grid, and
 flash body live in ``paged.py`` (``paged_decode_attention_fwd``), and
 passing the per-page-per-head scale pools switches it into quantized
-mode — the scale block for a grid step rides the *same* block-table
-index map as its KV block (a ``(1, 1, 1, 1)`` tile of the ``(Hkv, P)``
-scale pool, ``paged.scale_tiles``), and the dequant fuses into
-``flash_decode_step`` as one scalar multiply per block after the DMA.  The pools never exist
-densely in HBM at bf16.
+mode — each page's scales ride the *same* index map as its KV block (a
+``(Hkv, 1, 1, 1)`` tile of the ``(Hkv, P)`` scale pool,
+``paged.scale_tiles``), and the dequant fuses into the body as one
+multiply per head after the DMA.  The pools never exist densely in HBM
+at bf16.
 
 Logical re-paging works unchanged: a physical page splits into ``r``
 contiguous logical pages that all inherit the physical page's scale

@@ -369,6 +369,8 @@ class Engine:
         m.gauge("serve.requeue_peak_depth")
         for prog in ("prefill", "admit", "step", "spec"):
             m.counter(f"serve.compiles.{prog}")
+        m.counter("serve.decode.kv_pages_live")
+        m.counter("serve.decode.kv_pages_table")
         self._admit_seq = np.zeros((slots,), np.int64)
         self._seq = 0
         self._key = jax.random.PRNGKey(sc.seed)
@@ -1276,10 +1278,23 @@ class Engine:
             if not self._active_h.any():
                 return False
             sp.set_metadata(batch=int(self._active_h.sum()))
+            if self.paged:
+                self._count_kv_pages()
             nan_slots, stall = self._draw_faults()
             if self.spec:
                 return self._spec_step(nan_slots, stall)
             return self._plain_step(nan_slots, stall)
+
+    def _count_kv_pages(self):
+        """Table entries the paged kernel reads this step against those
+        its table holds: pages of each active slot's post-write length
+        (``serve.decode.kv_pages_live``) and slots x table width
+        (``serve.decode.kv_pages_table``), from host state alone."""
+        live = -(-(self._len_h[self._active_h] + 1) // self.page_size)
+        self.metrics.counter("serve.decode.kv_pages_live").inc(
+            int(live.sum()))
+        self.metrics.counter("serve.decode.kv_pages_table").inc(
+            self.block_tables.size)
 
     def _plain_step(self, nan_slots: List[int], stall: float) -> bool:
         """One plain decode step: ensure pages, run the jitted step, take

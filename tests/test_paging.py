@@ -8,9 +8,11 @@ from repro.core import context as ctx
 from repro.kernels.decode_attention.ops import (decode_attention,
                                                 paged_decode_attention,
                                                 paged_decode_attention_op)
+from repro.kernels.decode_attention import paged as paged_kernel
 from repro.kernels.decode_attention.paged import repage
-from repro.kernels.decode_attention.ref import (decode_attention_ref,
-                                                gather_pages)
+from repro.kernels.decode_attention.ref import (
+    decode_attention_ref, gather_pages, paged_decode_attention_ref,
+    quant_paged_decode_attention_ref)
 from repro.serve import paging
 from repro.sharding.kernel_sharding import sharded_paged_decode_update_attend
 
@@ -251,6 +253,101 @@ def test_non_dividing_block_kv_clamps_to_divisor():
                                   page_size=32, block_kv=8)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=0, rtol=0)
+
+
+PS, TABLE = 16, 5          # pages of 16 tokens, 5 table entries a slot
+MIXED = [0, 1, PS, 2 * PS + 5, TABLE * PS]
+PARITY = {
+    "len_0": dict(lengths=[0, 0]),
+    "len_1": dict(lengths=[1, 1]),
+    "len_page_boundary": dict(lengths=[PS, 3 * PS]),
+    "len_full_table": dict(lengths=[TABLE * PS, TABLE * PS]),
+    "gqa_4": dict(hq=8, hkv=2),
+    "gqa_6": dict(hq=12, hkv=2),
+    "hkv_1": dict(hq=4, hkv=1),
+    "softcap": dict(softcap=30.0),
+    "window": dict(window=20),
+    "int8_scales": dict(quantized=True),
+    "nan_past_length": dict(nan_past_length=True),
+}
+
+
+@pytest.mark.parametrize("steps", ["one_run", "runs_of_2"])
+@pytest.mark.parametrize("case", sorted(PARITY))
+def test_paged_kernel_matches_reference(case, steps, monkeypatch):
+    """The paged kernel against the gathered dense oracle, with the whole
+    table in one grid step and in runs of two table entries (the
+    step budget shrunk to two pages).  In ``nan_past_length`` every
+    table entry past a slot's length names a real page filled with NaN:
+    the kernel never brings one into the result."""
+    c = dict(dict(hq=8, hkv=2, lengths=MIXED, softcap=None, window=None,
+                  quantized=False, nan_past_length=False), **PARITY[case])
+    hq, hkv, d = c["hq"], c["hkv"], 32
+    if steps == "runs_of_2":
+        monkeypatch.setattr(paged_kernel, "STEP_KV_BYTES",
+                            2 * hkv * PS * 2 * d * 4)
+    lengths = jnp.asarray(c["lengths"], jnp.int32)
+    b = lengths.shape[0]
+    n_pages = 1 + b * TABLE
+    kpg = _rand((hkv, n_pages, PS, d), 1)
+    vpg = _rand((hkv, n_pages, PS, d), 2)
+    q = _rand((b, hq, d), 3)
+    perm = np.random.default_rng(4).permutation(np.arange(1, n_pages))
+    bt = perm.reshape(b, TABLE).astype(np.int32)
+    live = -(-np.asarray(lengths) // PS)
+    past = np.arange(TABLE)[None, :] >= live[:, None]
+    if not c["nan_past_length"]:      # the allocator's table: NULL past it
+        bt[past] = paging.NULL_PAGE
+    bt = jnp.asarray(bt)
+    kw = dict(window=c["window"], softcap=c["softcap"])
+    if c["quantized"]:
+        from repro.quant import spec_for_storage
+        s = spec_for_storage(jnp.int8)
+        kpg, ks = s.quantize_pages(kpg)
+        vpg, vs = s.quantize_pages(vpg)
+        want = quant_paged_decode_attention_ref(
+            q, kpg, vpg, ks, vs, bt, lengths, return_residuals=True, **kw)
+        kw.update(k_scales=ks, v_scales=vs)
+        pools = (kpg, vpg)
+    else:
+        want = paged_decode_attention_ref(q, kpg, vpg, bt, lengths,
+                                          return_residuals=True, **kw)
+        pools = (kpg, vpg)
+        if c["nan_past_length"]:
+            dead = np.asarray(bt)[past]
+            pools = tuple(p.at[:, dead].set(jnp.nan) for p in pools)
+    acc, m, l = paged_kernel.paged_decode_attention_fwd(
+        q, *pools, bt, lengths, block_kv=PS // 2, **kw)
+    w_acc, w_m, w_l = want
+    assert np.isfinite(np.asarray(acc)).all()
+    np.testing.assert_allclose(np.asarray(l), np.asarray(w_l),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(acc), np.asarray(w_acc),
+                               atol=2e-5, rtol=2e-5)
+    has = np.asarray(w_l) > 0                 # m is -inf-like where empty
+    np.testing.assert_allclose(np.asarray(m)[has], np.asarray(w_m)[has],
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("ppb", [1, 2, 3, TABLE])
+def test_run_table_names_only_live_pages_and_ends_each_slot(ppb):
+    """Each slot's live runs fill its last grid steps in table order;
+    every other entry repeats a live page (or the slot's first page when
+    it is empty), never one past the length."""
+    b = len(MIXED)
+    bt = np.arange(1, 1 + b * TABLE, dtype=np.int32).reshape(b, TABLE)
+    lengths = np.asarray(MIXED, np.int32)
+    tbl = np.asarray(paged_kernel.run_table(
+        jnp.asarray(bt), jnp.asarray(lengths), PS, ppb))
+    nr = -(-TABLE // ppb)
+    assert tbl.shape == (b, nr * ppb)
+    for s, n in enumerate(lengths):
+        last = max(int(n) - 1, 0) // PS
+        assert set(tbl[s]) <= set(bt[s, :last + 1])
+        runs = max(-(-int(n) // (ppb * PS)), 1)
+        walk = tbl[s, (nr - runs) * ppb:]
+        live = [col for col in range(runs * ppb) if col <= last]
+        assert [walk[col] for col in live] == list(bt[s, :last + 1])
 
 
 def test_search_space_constraint_prunes_spanning_blocks():

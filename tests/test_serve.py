@@ -235,6 +235,27 @@ def test_paged_pages_allocated_on_demand_and_freed():
     assert (engine.block_tables == 0).all()
 
 
+def test_kv_page_counters_count_live_and_table_entries():
+    """Each paged step adds the pages of every active slot's post-write
+    length and the table's slots x width, from host state alone."""
+    engine, *_ = _engine(slots=2, cache_len=32, max_new=8, paged=True,
+                         page_size=8)
+    seen = []
+    plain = engine._plain_step
+
+    def record(*a):
+        seen.append(engine._len_h[engine._active_h] + 1)
+        return plain(*a)
+    engine._plain_step = record
+    reqs = [Request(rid=i, tokens=[1 + i] * (3 + 6 * i)) for i in range(3)]
+    engine.run_to_completion(reqs)
+    assert all(r.done for r in reqs) and seen
+    snap = engine.metrics.snapshot()["counters"]     # as --metrics-out has it
+    assert snap["serve.decode.kv_pages_table"] == len(seen) * 2 * 4
+    assert snap["serve.decode.kv_pages_live"] == sum(
+        int(np.sum(-(-lens // 8))) for lens in seen)
+
+
 def test_paged_pool_exhaustion_requeues_instead_of_losing_requests():
     """Regression: with an undersized (oversubscribed) pool, a group
     admission that cannot get pages must requeue — not leak pages, not

@@ -92,6 +92,19 @@ def _paged(q_shape, kv_dtype, *, kind="paged", quantized=False):
     return fn, shapes, name
 
 
+def _paged_cell(b, table, pages, hq=HQ, hkv=HKV, d=D, dv=D):
+    """The paged kernel at a benchmark cell's slots, table width and
+    pool, with no VMEM limit given: it must fit the default scoped
+    VMEM."""
+    from repro.kernels.decode_attention.paged import (
+        paged_decode_attention_fwd)
+    return (lambda q, k, v, bt, lens: paged_decode_attention_fwd(
+                q, k, v, bt, lens, block_kv=PS),
+            [((b, hq, d), bf), ((hkv, pages, PS, d), bf),
+             ((hkv, pages, PS, dv), bf), ((b, table), i32), ((b,), i32)],
+            "portable_paged_decode_attention")
+
+
 def _dense_decode():
     from repro.kernels.decode_attention.decode_attention import (
         decode_attention_fwd)
@@ -135,6 +148,10 @@ CASES = {
     "window_paged": lambda: _paged((B, HQ, D), bf, kind="window"),
     "window_paged_int8": lambda: _paged((B, HQ, D), i8, kind="window",
                                         quantized=True),
+    "paged_chat_cell": lambda: _paged_cell(32, 40, 700),
+    "paged_longctx_cell": lambda: _paged_cell(12, 64, 760),
+    "paged_wide_heads": lambda: _paged_cell(B, TABLE, PAGES, hq=16, hkv=16,
+                                            d=192, dv=128),
     "dense_decode": _dense_decode,
     "flash_prefill": _flash_prefill,
     "rmsnorm": _rmsnorm,
