@@ -74,6 +74,7 @@ class ModelConfig:
     use_qk_norm: bool = False
     use_post_norms: bool = False          # gemma2/3 sandwich norms
     rms_weight_offset: float = 0.0        # 1.0 for gemma family
+    rms_norm_eps: float = 1e-6            # every RMSNorm of the model
     rope_theta: float = 10_000.0
     rope_theta_local: Optional[float] = None   # gemma3 local layers use 10k
     mlp_activation: str = "silu"          # silu (gated) | gelu (ungated)

@@ -34,11 +34,19 @@ def _make_async_copy_tpu(src_ref, dst_ref, sem):
     return pltpu.make_async_copy(src_ref, dst_ref, sem)
 
 
+#: Scoped VMEM Mosaic gives a kernel that asks for none (v4, v5e).
+DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
+
+
 @declare_variant(I.compiler_params, match=match(device=arch("tpu")))
 def _compiler_params_tpu(dimension_semantics=None, vmem_limit_bytes=None):
+    """A scoped-VMEM request the default covers is left out: an explicit
+    limit, even the default's, changes how XLA places the buffers around
+    the kernel, so the kernel compiles as if it had asked for none."""
     kw = {}
     if dimension_semantics is not None:
         kw["dimension_semantics"] = tuple(dimension_semantics)
-    if vmem_limit_bytes is not None:
+    if vmem_limit_bytes is not None and \
+            vmem_limit_bytes > DEFAULT_SCOPED_VMEM:
         kw["vmem_limit_bytes"] = int(vmem_limit_bytes)
     return pltpu.CompilerParams(**kw)

@@ -39,7 +39,8 @@ def apply_norm(w, x, cfg: ModelConfig):
     uniformly — numerically the gemma convention, which is also the
     identity at init for every family.
     """
-    return rmsnorm(x, w.astype(x.dtype), weight_offset=1.0, eps=1e-6)
+    return rmsnorm(x, w.astype(x.dtype), weight_offset=1.0,
+                   eps=cfg.rms_norm_eps)
 
 
 def norm_param(d: int):

@@ -813,6 +813,7 @@ class Engine:
             with span("engine.admit.prefill") as sp:
                 toks = jnp.asarray([r.tokens + r.out for r in reqs],
                                    jnp.int32)
+                sp.set_metadata(rows=int(toks.size))   # group x length
                 logits, cache1 = self._jit_call("prefill", self._prefill,
                                                 sp, self.params, toks)
                 self._key, sub = jax.random.split(self._key)

@@ -349,9 +349,14 @@ def test_engine_spans_nest_and_count(tmp_path, monkeypatch, case):
     groups = []
     admit_group = Engine._admit_group
 
+    admitted = []
+
     def spy(self, reqs, plen):
         groups.append({"k": len(reqs), "plen": plen})
-        return admit_group(self, reqs, plen)
+        n = admit_group(self, reqs, plen)
+        if n:
+            admitted.append(n * plen)
+        return n
     monkeypatch.setattr(Engine, "_admit_group", spy)
     calls = []
 
@@ -379,6 +384,10 @@ def test_engine_spans_nest_and_count(tmp_path, monkeypatch, case):
     assert [s[4] for s in sorted(grp, key=lambda s: s[1])] == groups
     if case == "plain":
         assert groups == [{"k": 2, "plen": 5}, {"k": 1, "plen": 7}]
+    # each prefill names its rows (admitted group x prompt length)
+    pre = sorted((s for s in spans if s[0] == "repro.engine.admit.prefill"),
+                 key=lambda s: s[1])
+    assert [s[4]["rows"] for s in pre] == admitted
     for s in spans:
         if s[0] == "repro.engine.step.sync":
             assert _inside(s, steps)

@@ -78,6 +78,100 @@ def test_rmsnorm_native_twin_identical():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+V5E_VMEM = 48 * 2 ** 20          # the v5e tuning row's vmem_limit
+
+
+@pytest.mark.parametrize("d", [4096, 6144, 8192])
+@pytest.mark.parametrize("itemsize", [2, 4])
+def test_rmsnorm_block_fits_scoped_vmem(d, itemsize):
+    """The row block stays within the VMEM limit at every row count, and
+    the grid covers whole blocks only: all the rows in one block, or a
+    multiple of 8 rows dividing the count rounded up to 8.  The tuned 512
+    survives where it fits."""
+    from repro.kernels.rmsnorm.rmsnorm import (
+        row_blocks, step_vmem_bytes, vmem_request)
+    for rows in (1, 12, 32, 256, 2048, 3584, 4001, 4032, 4096, 5632,
+                 7168, 8192):
+        b, padded = row_blocks(rows, d, itemsize, 512, V5E_VMEM)
+        assert 1 <= b <= min(rows, 512)
+        assert padded % b == 0 and rows <= padded < rows + 8
+        assert b == rows or b % 8 == 0
+        assert step_vmem_bytes(b, d, itemsize) <= \
+            vmem_request(b, d, itemsize) <= V5E_VMEM
+    fits = step_vmem_bytes(512, d, itemsize) <= V5E_VMEM
+    assert (row_blocks(8192, d, itemsize, 512, V5E_VMEM)[0] == 512) == fits
+
+
+def test_rmsnorm_block_at_granite_shapes_is_unchanged():
+    """granite-8b's cells (d 4096, bf16) keep the v5e tuning's 512-row
+    block (all the rows of a shorter prompt) in every prefill up to 3584
+    rows, and a decode batch is one block of all its rows, within the
+    default scoped VMEM, so it asks for none."""
+    from repro.core.targets.tpu import DEFAULT_SCOPED_VMEM
+    from repro.kernels.rmsnorm.rmsnorm import row_blocks, vmem_request
+    for rows in (256, 512, 1024, 2048, 3072, 3584):
+        assert row_blocks(rows, 4096, 2, 512, V5E_VMEM) == \
+            (min(rows, 512), rows)
+    for slots in (1, 8, 12, 32):
+        assert row_blocks(slots, 4096, 2, 512, V5E_VMEM) == (slots, slots)
+        assert vmem_request(slots, 4096, 2) <= DEFAULT_SCOPED_VMEM
+
+
+def test_rmsnorm_block_at_internlm2_shapes():
+    """InternLM2's prefills (d 6144) take the largest whole block that
+    fits the v5e limit; a decode batch is one block."""
+    from repro.kernels.rmsnorm.rmsnorm import row_blocks
+    assert row_blocks(32, 6144, 2, 512, V5E_VMEM) == (32, 32)
+    for rows, block in ((256, 256), (512, 256), (2048, 256), (4096, 256),
+                        (5632, 352), (7168, 256)):
+        assert row_blocks(rows, 6144, 2, 512, V5E_VMEM) == (block, rows)
+
+
+@pytest.mark.parametrize("rows", [40, 41, 64])
+def test_rmsnorm_whole_blocks_match_reference(rows):
+    """Blocks smaller than the row count, a count padded up to 8 among
+    them, give the reference's output row for row."""
+    from repro.kernels.rmsnorm.rmsnorm import rmsnorm_fwd, row_blocks
+    x = _rand((rows, 128), jnp.float32, 0)
+    w = _rand((128,), jnp.float32, 1, scale=0.1)
+    block, padded = row_blocks(rows, 128, 4, 24, 16 * 2 ** 20)
+    assert block < rows and padded % block == 0
+    got = rmsnorm_fwd(x, w, eps=1e-5, weight_offset=1.0, block_rows=24)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(rmsnorm_ref(x, w, eps=1e-5,
+                                                weight_offset=1.0)),
+        rtol=1e-5, atol=1e-5)
+
+
+def test_rms_norm_eps_reaches_the_kernel():
+    """``ModelConfig.rms_norm_eps`` is the eps of the model's norms: at
+    small d and small activations 1e-5 and 1e-6 give other outputs, each
+    the reference's at its eps."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.models.layers import apply_norm
+    x = _rand((8, 64), jnp.float32, 0, scale=3e-3)
+    w = _rand((64,), jnp.float32, 1, scale=0.1)
+    base = get_config("granite-8b")
+    outs = {}
+    for eps in (1e-5, 1e-6):
+        cfg = dataclasses.replace(base, rms_norm_eps=eps)
+        outs[eps] = np.asarray(apply_norm(w, x, cfg))
+        np.testing.assert_allclose(
+            outs[eps], np.asarray(rmsnorm_ref(x, w, eps=eps,
+                                              weight_offset=1.0)),
+            rtol=1e-5, atol=1e-5)
+    assert np.max(np.abs(outs[1e-5] - outs[1e-6])) > 0.1
+
+
+def test_configs_state_their_norm_eps_and_rope_base():
+    from repro.configs import get_config
+    ivl = get_config("internvl2-26b")
+    assert (ivl.rope_theta, ivl.rms_norm_eps) == (1e6, 1e-5)
+    granite = get_config("granite-8b")
+    assert (granite.rope_theta, granite.rms_norm_eps) == (1e4, 1e-6)
+
+
 def test_rmsnorm_grad():
     x = _rand((16, 128), jnp.float32)
     w = _rand((128,), jnp.float32, 1)

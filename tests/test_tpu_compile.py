@@ -131,6 +131,12 @@ def _rmsnorm():
             [((2048, 4096), bf), ((4096,), bf)], "portable_rmsnorm")
 
 
+def _rmsnorm_tuned(rows, d):
+    from repro.kernels.rmsnorm.ops import rmsnorm
+    return (lambda x, w: rmsnorm(x, w), [((rows, d), bf), ((d,), bf)],
+            "portable_rmsnorm")
+
+
 def _gmm():
     from repro.kernels.gmm.gmm import gmm_fwd
     return (lambda a, b, g: gmm_fwd(a, b, g, block_c=128, block_n=512,
@@ -155,6 +161,14 @@ CASES = {
     "dense_decode": _dense_decode,
     "flash_prefill": _flash_prefill,
     "rmsnorm": _rmsnorm,
+    # prefills that ran out of scoped VMEM at the v5e tuning's fixed
+    # 512-row block (d_model 4096 past 3584 rows, InternLM2's d_model
+    # 6144), through the op at the v5e tuning row; 4001 rows pad to 4008
+    "rmsnorm_4032x4096": lambda: _rmsnorm_tuned(4032, 4096),
+    "rmsnorm_8192x4096": lambda: _rmsnorm_tuned(8192, 4096),
+    "rmsnorm_4096x6144": lambda: _rmsnorm_tuned(4096, 6144),
+    "rmsnorm_7168x6144": lambda: _rmsnorm_tuned(7168, 6144),
+    "rmsnorm_4001x6144": lambda: _rmsnorm_tuned(4001, 6144),
     "gmm": _gmm,
 }
 
