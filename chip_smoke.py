@@ -145,8 +145,10 @@ def main():
     admit_s = time.perf_counter() - t0
     print(f"bring-up: admission of {len(reqs)} requests in 2 groups "
           f"(compile + run) {admit_s:.2f} s", flush=True)
-    state = (eng.params, eng.caches, eng.cur_tok, eng.lengths,
-             jnp.asarray(eng.block_tables))
+    # a copy of the pools: each engine step consumes (donates) the ones
+    # it is given
+    state = (eng.params, jax.tree.map(jnp.copy, eng.caches), eng.cur_tok,
+             eng.lengths, jnp.asarray(eng.block_tables))
 
     # each step ends in the engine's one device_get, so host time is the
     # step's; the first also compiles it

@@ -90,21 +90,24 @@ combine_partials = _ref.combine_partials
 # ------------------------------------------------------------ paged ------
 
 def _paged_ref_impl(q, k_pages, v_pages, block_tables, lengths, *, window,
-                    softcap, scale, page_size, block_kv):
+                    softcap, scale, page_size, block_kv, layer=None):
     # Paging granularity is a scheduling choice; the oracle is the
     # page-gathered dense computation, identical for every (page_size,
     # block_kv) candidate — which is exactly what makes them tunable.
     del page_size, block_kv
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
     return _ref.paged_decode_attention_ref(
         q, k_pages, v_pages, block_tables, lengths, window=window,
         softcap=softcap, scale=scale, return_residuals=True)
 
 
 def _paged_kernel_impl(q, k_pages, v_pages, block_tables, lengths, *, window,
-                       softcap, scale, page_size, block_kv):
+                       softcap, scale, page_size, block_kv, layer=None):
     return _paged.paged_decode_attention_fwd(
         q, k_pages, v_pages, block_tables, lengths, window=window,
-        softcap=softcap, scale=scale, page_size=page_size, block_kv=block_kv)
+        softcap=softcap, scale=scale, page_size=page_size, block_kv=block_kv,
+        layer=layer)
 
 
 def _paged_example(key):
@@ -146,18 +149,22 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            scale: Optional[float] = None,
                            page_size: Optional[int] = None,
                            block_kv: Optional[int] = None,
+                           layer=None,
                            return_residuals: bool = False):
     """Single-token GQA decode attention over a paged KV pool.
 
-    q: (B, Hq, D); pools: (Hkv, P, ps, D); block_tables: (B, T) int32
-    page ids; lengths: (B,) valid prefix.  Semantics match
-    ``decode_attention`` over the page-gathered dense cache; tunables
-    (``page_size`` logical granularity, ``block_kv`` tokens per grid
-    step) default to the per-target tuning table.
+    q: (B, Hq, D); pools: (Hkv, P, ps, D), or every layer's pools
+    stacked (L, Hkv, P, ps, D) and read at the int32 scalar ``layer``;
+    block_tables: (B, T) int32 page ids; lengths: (B,) valid prefix.
+    Semantics match ``decode_attention`` over the page-gathered dense
+    cache of ``pool[layer]``; tunables (``page_size`` logical
+    granularity, ``block_kv`` tokens per grid step) default to the
+    per-target tuning table.
     """
     acc, m, l = paged_decode_attention_op(
         q, k_pages, v_pages, block_tables, lengths, window=window,
-        softcap=softcap, scale=scale, page_size=page_size, block_kv=block_kv)
+        softcap=softcap, scale=scale, page_size=page_size, block_kv=block_kv,
+        layer=layer)
     if return_residuals:
         return acc, m, l
     l_safe = jnp.where(l == 0.0, 1.0, l)

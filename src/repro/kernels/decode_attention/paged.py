@@ -5,10 +5,11 @@ pool instead of one contiguous row per slot; a per-slot block table
 names the pages that hold its sequence.  This kernel runs the same
 online-softmax accumulation as the dense decode kernel (the math is
 ``online_softmax_update``, shared), but the KV blocks reach VMEM
-through a block-table index map: the table and lengths ride as
-scalar-prefetch operands (``kernel_call(num_scalar_prefetch=2)``, the
-runtime facade's analogue of OpenMP's device-resident control data), so
-the DMA engine can resolve ``pool[:, page]`` before the body runs.
+through a block-table index map: the table and lengths (and, for the
+prefix-table kernel, the layer index) ride as scalar-prefetch operands
+(``kernel_call(num_scalar_prefetch=...)``, the runtime facade's
+analogue of OpenMP's device-resident control data), so the DMA engine
+can resolve ``pool[layer, :, page]`` before the body runs.
 One kernel source serves compiled TPU and the CPU interpreter — the
 gather is expressed in the portable BlockSpec layer, not in
 target-specific scatter/gather intrinsics.
@@ -17,8 +18,12 @@ Layouts
   q           (B, Hq, D)        one new token per slot
   k/v pools   (Hkv, P, ps, D)   head-major page pool; page 0 is the
                                 allocator's reserved null page
+              or (L, Hkv, P, ps, D) with ``layer``: every layer's pool
+                                stacked, read in place at one layer
   block_tables(B, T) int32      page id per (slot, logical page)
   lengths     (B,)   int32      valid tokens per slot
+  layer       ()     int32      which stacked pool to read (scalar
+                                prefetch; 0 for a 4-D pool)
 
 Grid: ``(B, cdiv(T, ppb))`` — one step per slot and *run* of ``ppb``
 table entries, every local KV head at once.  Each entry of a run is its
@@ -111,15 +116,16 @@ def run_table(block_tables, lengths, page_size: int, ppb: int):
                                axis=1)
 
 
-def _paged_decode_kernel(tbl_ref, len_ref, q_ref, *refs, rt: DeviceRuntime,
-                         scale: float, window: Optional[int],
-                         softcap: Optional[float], page_size: int,
-                         block_kv: int, ppb: int, quantized: bool):
-    # operands: the run table and lengths (scalar prefetch, consumed by
-    # the maps and here), q, then per table entry of the run its K and
-    # V page [and their scale tiles], then three outputs and three
-    # scratch accumulators.
-    del tbl_ref
+def _paged_decode_kernel(tbl_ref, len_ref, layer_ref, q_ref, *refs,
+                         rt: DeviceRuntime, scale: float,
+                         window: Optional[int], softcap: Optional[float],
+                         page_size: int, block_kv: int, ppb: int,
+                         quantized: bool):
+    # operands: the run table, lengths and layer (scalar prefetch,
+    # consumed by the maps and here), q, then per table entry of the run
+    # its K and V page [and their scale tiles], then three outputs and
+    # three scratch accumulators.
+    del tbl_ref, layer_ref
     per = 4 if quantized else 2
     pages = [refs[i * per:(i + 1) * per] for i in range(ppb)]
     o_ref, m_out_ref, l_out_ref, acc_ref, m_ref, l_ref = refs[ppb * per:]
@@ -162,21 +168,22 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, *refs, rt: DeviceRuntime,
 
 
 def repage(pool, block_tables, page_size: int):
-    """Re-view ``(H, P, ps, D)`` pool + table at a smaller logical page.
+    """Re-view ``(..., H, P, ps, D)`` pool + table at a smaller logical
+    page.
 
     ``page_size`` must divide the physical page size; each physical
     page becomes ``r = ps // page_size`` logical pages (a contiguous
     axis split — no data movement) and the block table expands to name
     them.  Identity when sizes already agree.
     """
-    h, p, ps, d = pool.shape
+    *lead, p, ps, d = pool.shape
     if page_size == ps:
         return pool, block_tables
     if ps % page_size:
         raise ValueError(f"logical page_size {page_size} must divide the "
                          f"pool's physical page size {ps}")
     r = ps // page_size
-    pool = pool.reshape(h, p * r, page_size, d)
+    pool = pool.reshape(*lead, p * r, page_size, d)
     bt = (block_tables[:, :, None] * r
           + jnp.arange(r, dtype=block_tables.dtype)[None, None, :])
     return pool, bt.reshape(block_tables.shape[0], -1)
@@ -189,17 +196,17 @@ def repage_scales(scales, page_size: int, ps_phys: int):
     if page_size == ps_phys:
         return scales
     r = ps_phys // page_size
-    h, p = scales.shape
-    return jnp.repeat(scales, r, axis=1).reshape(h, p * r)
+    *lead, p = scales.shape
+    return jnp.repeat(scales, r, axis=-1).reshape(*lead, p * r)
 
 
 def scale_tiles(scales):
-    """``(H, P)`` scale pool as ``(H, P, 1, 1)``: a page's scales then
-    ride a ``(H|1, 1, 1, 1)`` VMEM block whose last two dims equal the
-    array's, which Mosaic accepts (a ``(1, 1)`` block of ``(H, P)`` is
-    refused, in VMEM and SMEM alike).  A free reshape.  Prefetching
-    the pools whole into SMEM (1 MiB on v5e) would instead cap two f32
-    pools of 8 heads near 16k pages."""
+    """``(..., H, P)`` scale pool as ``(..., H, P, 1, 1)``: a page's
+    scales then ride a ``(H|1, 1, 1, 1)`` VMEM block whose last two dims
+    equal the array's, which Mosaic accepts (a ``(1, 1)`` block of
+    ``(H, P)`` is refused, in VMEM and SMEM alike).  A free reshape.
+    Prefetching the pools whole into SMEM (1 MiB on v5e) would instead
+    cap two f32 pools of 8 heads near 16k pages."""
     return scales.reshape(*scales.shape, 1, 1)
 
 
@@ -209,17 +216,20 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
                                scale: Optional[float] = None,
                                page_size: Optional[int] = None,
                                block_kv: int = 64,
-                               k_scales=None, v_scales=None,
+                               k_scales=None, v_scales=None, layer=None,
                                rt: Optional[DeviceRuntime] = None):
-    """q: (B, Hq, D); pools: (Hkv, P, ps, D); block_tables: (B, T);
-    lengths: (B,) int32.
+    """q: (B, Hq, D); pools: (Hkv, P, ps, D), or (L, Hkv, P, ps, D)
+    read at ``layer``; block_tables: (B, T); lengths: (B,) int32.
 
     One grid step per slot and run of table entries, all KV heads per
     step; the walk stops at each slot's length (module docstring).
-    Returns unnormalized (acc (B,Hq,Dv), m (B,Hq), l (B,Hq)) — the same
-    residual contract as the dense decode kernel, so callers normalize
-    or LSE-combine identically.  With ``k_scales``/``v_scales``
-    (per-page-per-head (Hkv, P) f32; both or neither) the pools are
+    ``layer`` (an int32 scalar, traced or not) rides the scalar
+    prefetch into the K/V index maps, so a stacked pool is read in
+    place and never sliced.  Returns unnormalized (acc (B,Hq,Dv),
+    m (B,Hq), l (B,Hq)) — the same residual contract as the dense
+    decode kernel, so callers normalize or LSE-combine identically.
+    With ``k_scales``/``v_scales`` (per-page-per-head (Hkv, P) f32,
+    or (L, Hkv, P) beside stacked pools; both or neither) the pools are
     quantized storage and the per-block dequant fuses into the flash
     body (the quant_paged_decode_attention op).
     """
@@ -227,10 +237,17 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
     rt = rt or runtime()
     quantized = k_scales is not None
     assert (v_scales is None) == (k_scales is None)
+    if layer is None:
+        # one stacked layer: a free leading axis, read at layer 0
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if quantized:
+            k_scales, v_scales = k_scales[None], v_scales[None]
+        layer = 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     b, hq, d = q.shape
-    hkv = k_pages.shape[0]
-    ps_phys = k_pages.shape[2]
-    dv = v_pages.shape[3]
+    hkv = k_pages.shape[1]
+    ps_phys = k_pages.shape[3]
+    dv = v_pages.shape[4]
     page_size = ps_phys if page_size is None else page_size
     if quantized:
         k_scales = repage_scales(k_scales, page_size, ps_phys)
@@ -262,25 +279,27 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
         softcap=softcap, page_size=page_size, block_kv=block_kv, ppb=ppb,
         quantized=quantized)
 
-    def slot_map(ib, ir, tbl_ref, len_ref):
-        del ir, tbl_ref, len_ref
+    def slot_map(ib, ir, tbl_ref, len_ref, layer_ref):
+        del ir, tbl_ref, len_ref, layer_ref
         return (ib, 0, 0, 0)
 
     def page_map(i):
-        def index(ib, ir, tbl_ref, len_ref):
+        def index(ib, ir, tbl_ref, len_ref, layer_ref):
             del len_ref
-            return (0, tbl_ref[ib, ir * ppb + i], 0, 0)
+            return (layer_ref[0], 0, tbl_ref[ib, ir * ppb + i], 0, 0)
         return index
 
     in_specs = [pl.BlockSpec((1, hkv, g8, d), slot_map)]
     operands = [qg]
     for i in range(ppb):
-        in_specs += [pl.BlockSpec((hkv, 1, page_size, d), page_map(i)),
-                     pl.BlockSpec((hkv, 1, page_size, dv), page_map(i))]
+        in_specs += [
+            pl.BlockSpec((None, hkv, 1, page_size, d), page_map(i)),
+            pl.BlockSpec((None, hkv, 1, page_size, dv), page_map(i))]
         operands += [k_pages, v_pages]
         if quantized:
             # a page's scale tiles ride the same map as its KV block
-            in_specs += [pl.BlockSpec((hkv, 1, 1, 1), page_map(i))] * 2
+            in_specs += [pl.BlockSpec((None, hkv, 1, 1, 1),
+                                      page_map(i))] * 2
             operands += [scale_tiles(k_scales), scale_tiles(v_scales)]
 
     acc, m, l = kernel_call(
@@ -291,7 +310,7 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
             jax.ShapeDtypeStruct((b, hkv, g8, LANES), jnp.float32),
         ),
         grid=(b, pl.cdiv(n_pages, ppb)),
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         in_specs=in_specs,
         out_specs=(
             pl.BlockSpec((1, hkv, g8, dv), slot_map),
@@ -307,7 +326,7 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, block_tables, lengths, *,
         name=("portable_quant_paged_decode_attention" if quantized
               else "portable_paged_decode_attention"),
         rt=rt,
-    )(run_table(bt, lengths, page_size, ppb), lengths, *operands)
+    )(run_table(bt, lengths, page_size, ppb), lengths, layer, *operands)
 
     acc = acc[:, :, :group].reshape(b, hq, dv)
     m = m[:, :, :group, 0].reshape(b, hq)

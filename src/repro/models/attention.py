@@ -166,7 +166,7 @@ def project_kv(p, x_enc, cfg: ModelConfig, positions=None, theta=None):
 def decode_attn(p, x, cache_k, cache_v, lengths, cfg: ModelConfig, *,
                 kind: str = "global", theta=None, ring: bool = False,
                 block_tables=None, cache_scales=None,
-                windowed: bool = False):
+                windowed: bool = False, layer=None):
     """One-token decode.  x: (B, 1, d).  Returns (out (B,1,d), new_k,
     new_v) — the new token's K/V is written into the cache *inside* the
     fused update+attend wrapper (sharded in sharding/kernel_sharding.py)
@@ -176,6 +176,9 @@ def decode_attn(p, x, cache_k, cache_v, lengths, cfg: ModelConfig, *,
     block_tables: (B, T) int32 — cache_k/cache_v are then head-major
     paged pools (Hkv, P, ps, D) and the new token's K/V is scattered
     into the slot's current page (paged serving; incompatible with ring).
+    layer: an int32 scalar — the plain (unquantized, prefix-table) paged
+    pools are then every layer's, stacked (L, Hkv, P, ps, D), written and
+    read in place at ``layer``.
     windowed=True: block_tables is the (B, T_w) *ring* table of a
     paged sliding-window layer (``kind`` must be 'local') and the step
     routes through the O(window) ring-table kernel.
@@ -206,7 +209,7 @@ def decode_attn(p, x, cache_k, cache_v, lengths, cfg: ModelConfig, *,
                 f"{kind!r}, window={cfg.window}): local layers page "
                 f"through windowed ring tables (windowed=True), not "
                 f"dense rings")
-        ps = cache_k.shape[2]
+        ps = cache_k.shape[-2]
         if windowed:
             if kind != "local" or cfg.window is None:
                 raise ValueError(
@@ -245,7 +248,7 @@ def decode_attn(p, x, cache_k, cache_v, lengths, cfg: ModelConfig, *,
             q, k, v, cache_k, cache_v, block_tables, write_page, write_off,
             (lengths + 1).astype(jnp.int32),
             window=window,
-            softcap=cfg.attn_softcap, page_size=ps)
+            softcap=cfg.attn_softcap, page_size=ps, layer=layer)
         o = jnp.einsum("bhk,hkd->bd", out, p["wo"].astype(xd))[:, None, :]
         return o, ck, cv
 
@@ -374,13 +377,14 @@ def apply_mla(p, x, cfg: ModelConfig, positions=None,
 
 
 def decode_mla(p, x, cache_k, cache_v, lengths, cfg: ModelConfig,
-               block_tables=None, cache_scales=None):
+               block_tables=None, cache_scales=None, layer=None):
     """MLA decode.  We cache the *materialized* per-head K/V (simple
     variant; latent-cache decode is a further memory optimization —
     DESIGN.md notes it as future work).  With ``block_tables`` the
-    caches are paged pools, as in ``decode_attn``; with
-    ``cache_scales`` they are quantized paged pools and the 5-tuple
-    (out, k, v, ks, vs) comes back."""
+    caches are paged pools, as in ``decode_attn`` (stacked and read at
+    ``layer`` when it is given); with ``cache_scales`` they are
+    quantized paged pools and the 5-tuple (out, k, v, ks, vs) comes
+    back."""
     m: MLAConfig = cfg.mla
     b = x.shape[0]
     h = cfg.num_heads
@@ -402,7 +406,7 @@ def decode_mla(p, x, cache_k, cache_v, lengths, cfg: ModelConfig,
 
     qk_dim = m.qk_nope_head_dim + m.qk_rope_head_dim
     if block_tables is not None:
-        ps = cache_k.shape[2]
+        ps = cache_k.shape[-2]
         write_page, write_off = _page_coords(block_tables, lengths, ps)
         if cache_scales is not None:
             out, ck, cv, ks, vs = quant_paged_decode_update_attend(
@@ -416,7 +420,7 @@ def decode_mla(p, x, cache_k, cache_v, lengths, cfg: ModelConfig,
         out, ck, cv = paged_decode_update_attend(
             q_full, k_full, v, cache_k, cache_v, block_tables, write_page,
             write_off, (lengths + 1).astype(jnp.int32),
-            scale=qk_dim ** -0.5, page_size=ps)
+            scale=qk_dim ** -0.5, page_size=ps, layer=layer)
     else:
         out, ck, cv = decode_update_attend(
             q_full, k_full, v, cache_k, cache_v, lengths.astype(jnp.int32),

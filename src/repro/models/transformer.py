@@ -276,7 +276,7 @@ def apply_layer_prefill(p, x, cfg: ModelConfig, kind: str, is_moe: bool,
 
 
 def apply_layer_decode(p, x, cache, cfg: ModelConfig, kind: str,
-                       is_moe: bool, lengths, block_tables=None):
+                       is_moe: bool, lengths, block_tables=None, layer=None):
     """One-token layer step.  x: (B,1,d).
 
     A cache carrying ``kp``/``vp`` holds paged pools (serve/paging.py)
@@ -288,7 +288,9 @@ def apply_layer_decode(p, x, cache, cfg: ModelConfig, kind: str,
     that also carries ``ks``/``vs`` scale pools holds *quantized* pools
     (repro.quant) and routes through the re-quantizing write +
     fused-dequant kernel.  Recurrent/cross caches are never paged and
-    take their usual path.
+    take their usual path.  With ``layer`` (an int32 scalar) the plain
+    ``kp``/``vp`` pools are every layer's, stacked, and the step writes
+    and reads them in place at ``layer`` (``seg_apply_decode``'s carry).
     """
     h = L.apply_norm(p["ln1"], x, cfg)
     new_cache = dict(cache)
@@ -318,12 +320,13 @@ def apply_layer_decode(p, x, cache, cfg: ModelConfig, kind: str,
             if cfg.mla:
                 out = A.decode_mla(p["attn"], h, ck_in, cv_in,
                                    lengths, cfg, block_tables=bt,
-                                   cache_scales=scales)
+                                   cache_scales=scales, layer=layer)
             else:
                 out = A.decode_attn(p["attn"], h, ck_in, cv_in,
                                     lengths, cfg, kind=kind, ring=ring,
                                     theta=_theta(cfg, kind),
-                                    block_tables=bt, cache_scales=scales)
+                                    block_tables=bt, cache_scales=scales,
+                                    layer=layer)
         if quantized:
             y, ck, cv, ks, vs = out
             new_cache["ks"], new_cache["vs"] = ks, vs
@@ -475,19 +478,50 @@ def seg_apply_prefill(seg_p, x, cfg: ModelConfig, plan: SegmentPlan,
     return x, caches
 
 
+POOL_KEYS = ("kp", "vp")
+
+
+def pool_in_place(cache) -> bool:
+    """Whether a decode-cache entry's pools ride ``seg_apply_decode``'s
+    carry: plain paged ``kp``/``vp`` pools, with no ``ks``/``vs`` scale
+    pools and no ``kw``/``vw`` window group."""
+    return "kp" in cache and "ks" not in cache and "kw" not in cache
+
+
 def seg_apply_decode(seg_p, caches, x, cfg: ModelConfig, plan: SegmentPlan,
                      lengths, block_tables=None):
-    def body(x_, xs):
-        lp, cs = xs
-        new = []
-        for i, (kind, is_moe) in enumerate(plan.block):
-            x_, nc = apply_layer_decode(lp[i], x_, cs[i], cfg, kind, is_moe,
-                                        lengths, block_tables=block_tables)
-            new.append(nc)
-        return x_, tuple(new)
+    """One decode step through a segment's stacked layers.
 
-    x, new_caches = jax.lax.scan(body, x, (seg_p, caches))
-    return x, new_caches
+    The plain paged pools of an entry (``pool_in_place``) ride the
+    scan's carry whole, beside the layer index: each layer writes its
+    token's K/V into them and the paged kernel reads them at that
+    index, so no layer slices a pool out or writes one back, and with
+    the step's caches donated the pools are updated in place.  The
+    weights and every other cache leaf stay in ``xs``/``ys``.
+    """
+    carried = [pool_in_place(c) for c in caches]
+    pools = tuple({k: c[k] for k in POOL_KEYS} if keep else {}
+                  for c, keep in zip(caches, carried))
+    rest = tuple({k: v for k, v in c.items()
+                  if not (keep and k in POOL_KEYS)}
+                 for c, keep in zip(caches, carried))
+
+    def body(carry, xs):
+        x_, layer, pools_ = carry
+        lp, cs = xs
+        new_pools, new_rest = [], []
+        for i, (kind, is_moe) in enumerate(plan.block):
+            x_, nc = apply_layer_decode(
+                lp[i], x_, {**cs[i], **pools_[i]}, cfg, kind, is_moe,
+                lengths, block_tables=block_tables,
+                layer=layer if carried[i] else None)
+            new_pools.append({k: nc.pop(k) for k in pools_[i]})
+            new_rest.append(nc)
+        return (x_, layer + 1, tuple(new_pools)), tuple(new_rest)
+
+    (x, _, pools), rest = jax.lax.scan(
+        body, (x, jnp.int32(0), pools), (seg_p, rest))
+    return x, tuple({**r, **p} for r, p in zip(rest, pools))
 
 
 def seg_apply_spec_decode(seg_p, caches, x, cfg: ModelConfig,

@@ -37,6 +37,10 @@ autotuner's per-target winner for ``paged_decode_attention``.  With
 ``kv_dtype`` the pools quantize (int8 everywhere, fp8-e4m3 where the
 target's ISA supports it — repro.quant resolves with clean fallback)
 and decode runs the fused-dequant kernel; ``"bf16"`` is passthrough.
+The step and admission are jitted with their caches donated, and the
+decode scan carries plain pools whole, so both update the pool in
+place; ``self.caches`` is always the tree the last call returned
+(DESIGN.md §10).
 
 Termination: a slot finishes when it has emitted ``max_new_tokens``,
 sampled ``eos_id``, or its cache is truly full — ``lengths ==
@@ -96,6 +100,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.registry import Model
+from repro.models.transformer import pool_in_place
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import span
 from repro.serve import paging
@@ -371,6 +376,10 @@ class Engine:
             m.counter(f"serve.compiles.{prog}")
         m.counter("serve.decode.kv_pages_live")
         m.counter("serve.decode.kv_pages_table")
+        # cache entries whose pools the decode scan carries and updates
+        # in place (0 where every entry falls back to per-layer slices)
+        m.gauge("serve.decode.pool_in_place").set(sum(
+            pool_in_place(c) for seg in self.caches for c in seg))
         self._admit_seq = np.zeros((slots,), np.int64)
         self._seq = 0
         self._key = jax.random.PRNGKey(sc.seed)
@@ -391,8 +400,12 @@ class Engine:
 
         self._prefill = jax.jit(
             lambda p, t: model.prefill(p, t, sc.cache_len, {}))
-        self._step_fn = jax.jit(self._build_step())
-        self._admit_fn = jax.jit(self._build_admit())
+        # the step and admission consume the caches they are given
+        # (donated), so the pools are updated in place; every caller
+        # adopts the returned caches, on every path (DESIGN.md §10).
+        # The spec step keeps its own copy-out (not donated).
+        self._step_fn = jax.jit(self._build_step(), donate_argnums=1)
+        self._admit_fn = jax.jit(self._build_admit(), donate_argnums=0)
         self._spec_fn = jax.jit(self._build_spec_step()) if self.spec \
             else None
 
@@ -1160,8 +1173,9 @@ class Engine:
 
     def _watchdog_tripped(self, t0: float) -> bool:
         """Deadline check around one dispatch + device_get.  On a trip
-        the caller discards the step's un-committed results (device
-        state holds the *previous* step) and every active slot goes
+        the caller discards the step's un-committed results (lengths,
+        tokens and masks hold the *previous* step; the pool keeps the
+        step's rows past those lengths) and every active slot goes
         down the recovery ladder — re-prefill of the committed
         checkpoint keeps greedy outputs token-identical.  Detection
         happens once the transfer returns: a device wedged hard enough
@@ -1333,11 +1347,14 @@ class Engine:
             # THE one sync/step — the emitted-token counter piggybacks here
             nt, dn, bh, em = _device_get((next_tok, done, bad, emitted))
         with span("engine.step.commit"):
+            # the step consumed the donated caches: adopt the returned
+            # ones even when the step is discarded — its rows sit past
+            # the committed lengths of slots the trip requeues
+            self.caches = new_caches
             if self._watchdog_tripped(t0):
                 return True         # step discarded; active slots requeued
             self.lengths, self.active_mask, self.n_out = \
                 new_lengths, new_active, new_n_out
-            self.caches = new_caches
             self.cur_tok = next_tok
             nt, dn, bh = np.asarray(nt), np.asarray(dn), np.asarray(bh)
             tel = self.telemetry

@@ -254,12 +254,16 @@ def sharded_paged_decode_update_attend(q, k_new, v_new, k_pages, v_pages,
                                        softcap: Optional[float] = None,
                                        scale: Optional[float] = None,
                                        page_size: Optional[int] = None,
-                                       block_kv: Optional[int] = None):
+                                       block_kv: Optional[int] = None,
+                                       layer=None):
     """Fused page write + paged decode attention.
 
-    q: (B,Hq,D); k_new/v_new: (B,Hkv,D) rope'd; pools: (Hkv,P,ps,D);
+    q: (B,Hq,D); k_new/v_new: (B,Hkv,D) rope'd; pools: (Hkv,P,ps,D),
+    or every layer's pools stacked (L,Hkv,P,ps,D) and written and read
+    at the int32 scalar ``layer`` — the decode scan's in-place carry;
     block_tables: (B,T) int32; write_page/write_off/eff_len: (B,).
-    Returns (out (B,Hq,Dv), new k_pages, new v_pages).
+    Returns (out (B,Hq,Dv), new k_pages, new v_pages), the pools in the
+    shape they came in.
 
     The same §Perf-B.1 rule as the dense path: the pool scatter happens
     INSIDE the shard_map region so GSPMD never all-gathers the pool.
@@ -269,43 +273,52 @@ def sharded_paged_decode_update_attend(q, k_new, v_new, k_pages, v_pages,
     """
     mesh = maybe_mesh()
     b, hq, _ = q.shape
-    hkv = k_pages.shape[0]
+    one = layer is None                  # one layer's pools: a stack of 1
+    if one:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    layer = jnp.asarray(layer, jnp.int32)
+    hkv = k_pages.shape[1]
     kw = dict(window=window, softcap=softcap, scale=scale,
               page_size=page_size, block_kv=block_kv)
 
-    def update(kp, vp, kn, vn, page, off):
+    def update(kp, vp, kn, vn, page, off, li):
         # page 0 is the allocator's null page: freed slots park there, so
         # their (masked-out) writes land in trash instead of live pages.
-        kn = jnp.swapaxes(kn, 0, 1).astype(kp.dtype)      # (Hkv, B, D)
-        vn = jnp.swapaxes(vn, 0, 1).astype(vp.dtype)
-        kp = kp.at[:, page, off].set(kn)
-        vp = vp.at[:, page, off].set(vn)
-        return kp, vp
+        # One D-row per (slot, head): a scatter whose window is the minor
+        # dim alone keeps the pool's default layout, which the kernel
+        # reads (a (Hkv, D) window makes XLA re-lay the pool out and copy
+        # it back around the kernel).
+        heads = jnp.arange(kn.shape[1])[None, :]          # this shard's
+        at = (li, heads, page[:, None], off[:, None])
+        return (kp.at[at].set(kn.astype(kp.dtype)),       # (B, Hkv, D)
+                vp.at[at].set(vn.astype(vp.dtype)))
 
-    def body(q_, kn, vn, kp, vp, bt, page, off, ln):
-        kp, vp = update(kp, vp, kn, vn, page, off)
-        return (paged_decode_attention(q_, kp, vp, bt, ln, **kw), kp, vp)
+    def body(q_, kn, vn, kp, vp, bt, page, off, ln, li):
+        kp, vp = update(kp, vp, kn, vn, page, off, li)
+        return (paged_decode_attention(q_, kp, vp, bt, ln, layer=li, **kw),
+                kp, vp)
 
+    args = (q, k_new, v_new, k_pages, v_pages, block_tables, write_page,
+            write_off, eff_len, layer)
     if not _use_wrappers(mesh):
-        return body(q, k_new, v_new, k_pages, v_pages, block_tables,
-                    write_page, write_off, eff_len)
-
-    # no batch sharding here: every shard must see every slot's write
-    # (the pool has no batch dim a dp shard could own a slice of).
-    dp = None
-    tp = _tp(mesh)
-    if hq % tp == 0 and hkv % tp == 0:
-        qs, ns_ = P(dp, "model", None), P(dp, "model", None)
-        ps_ = P("model", None, None, None)
+        out, kp, vp = body(*args)
     else:
-        qs, ns_ = P(dp, None, None), P(dp, None, None)
-        ps_ = P(None, None, None, None)
-    return shard_map(
-        body, mesh=mesh,
-        in_specs=(qs, ns_, ns_, ps_, ps_, P(dp, None), P(dp), P(dp), P(dp)),
-        out_specs=(qs, ps_, ps_), check_vma=False)(
-        q, k_new, v_new, k_pages, v_pages, block_tables,
-        write_page, write_off, eff_len)
+        # no batch sharding here: every shard must see every slot's write
+        # (the pool has no batch dim a dp shard could own a slice of).
+        dp = None
+        tp = _tp(mesh)
+        if hq % tp == 0 and hkv % tp == 0:
+            qs, ns_ = P(dp, "model", None), P(dp, "model", None)
+            ps_ = P(None, "model", None, None, None)
+        else:
+            qs, ns_ = P(dp, None, None), P(dp, None, None)
+            ps_ = P(None, None, None, None, None)
+        out, kp, vp = shard_map(
+            body, mesh=mesh,
+            in_specs=(qs, ns_, ns_, ps_, ps_, P(dp, None), P(dp), P(dp),
+                      P(dp), P()),
+            out_specs=(qs, ps_, ps_), check_vma=False)(*args)
+    return (out, kp[0], vp[0]) if one else (out, kp, vp)
 
 
 def sharded_quant_paged_decode_update_attend(q, k_new, v_new,

@@ -195,6 +195,40 @@ def test_stall_watchdog_discards_step_and_recovers():
     assert st["recoveries"]["stall"] >= 1
 
 
+def test_donated_caches_are_adopted_through_faults():
+    """The step and admission consume (donate) the caches they are
+    given: after each engine call that ran one, the previous tree is
+    deleted and the engine holds the returned one, on the watchdog
+    trip's path and the NaN sentinel's too, and the drained outputs
+    stay token-identical to the un-faulted run."""
+    want = _reference_outputs()
+    eng = _engine(plan=FaultPlan(stall_s=0.5).at(4, "stall")
+                  .at(7, "nan_logits"))
+    reqs = _reqs()
+    for r in reqs:
+        eng.submit(r)
+    consumed, tripped = 0, False
+    for i in range(500):
+        before = jax.tree.leaves(eng.caches)
+        trips = eng.watchdog_trips
+        busy = eng.step()
+        if i == 0:
+            eng.watchdog_s = 0.25
+        gone = [x.is_deleted() for x in before]
+        assert all(gone) or not any(gone)
+        consumed += all(gone)
+        if eng.watchdog_trips > trips:
+            tripped = True
+            assert all(gone)            # the discarded step's pool too
+        assert not any(x.is_deleted() for x in jax.tree.leaves(eng.caches))
+        assert eng.audit() == [], eng.audit()
+        if not busy and not eng.queue and not eng.requeue:
+            break
+    assert tripped and consumed > len(reqs)
+    assert eng.stats()["recoveries"]["nan_logits"] >= 1
+    assert {r.rid: list(r.out) for r in reqs} == want
+
+
 def test_retry_budget_exhaustion_fails_explicitly():
     """Past max_retries the request finishes with status='failed' —
     never an exception out of the serve loop — and the other requests

@@ -329,6 +329,43 @@ def test_paged_kernel_matches_reference(case, steps, monkeypatch):
                                atol=2e-5, rtol=2e-5)
 
 
+N_LAYERS = 5
+
+
+@pytest.mark.parametrize("group", [4, 6])
+@pytest.mark.parametrize("layer", [0, N_LAYERS // 2, N_LAYERS - 1])
+def test_stacked_pool_kernel_reads_its_layer(layer, group):
+    """Every layer's pools stacked (L, Hkv, P, ps, D) and read at a
+    traced ``layer`` give exactly the 4-D kernel's result on
+    ``pool[layer]``, and so does the generic target's oracle."""
+    hkv, d = 2, 32
+    lengths = jnp.asarray(MIXED, jnp.int32)
+    b = lengths.shape[0]
+    n_pages = 1 + b * TABLE
+    kst = _rand((N_LAYERS, hkv, n_pages, PS, d), 1)
+    vst = _rand((N_LAYERS, hkv, n_pages, PS, d), 2)
+    q = _rand((b, group * hkv, d), 3)
+    perm = np.random.default_rng(4).permutation(np.arange(1, n_pages))
+    bt = jnp.asarray(perm.reshape(b, TABLE).astype(np.int32))
+    stacked = jax.jit(
+        lambda q, k, v, li: paged_kernel.paged_decode_attention_fwd(
+            q, k, v, bt, lengths, block_kv=PS // 2, layer=li))
+    got = stacked(q, kst, vst, jnp.int32(layer))
+    want = paged_kernel.paged_decode_attention_fwd(
+        q, kst[layer], vst[layer], bt, lengths, block_kv=PS // 2)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    with ctx.target("generic"):
+        oracle = jax.jit(lambda q, k, v, li: paged_decode_attention(
+            q, k, v, bt, lengths, page_size=PS, layer=li))(
+            q, kst, vst, jnp.int32(layer))
+    np.testing.assert_allclose(
+        np.asarray(oracle),
+        np.asarray(paged_decode_attention(q, kst[layer], vst[layer], bt,
+                                          lengths, page_size=PS)),
+        atol=2e-5, rtol=2e-5)
+
+
 @pytest.mark.parametrize("ppb", [1, 2, 3, TABLE])
 def test_run_table_names_only_live_pages_and_ends_each_slot(ppb):
     """Each slot's live runs fill its last grid steps in table order;
@@ -412,6 +449,36 @@ def test_fused_page_write_then_attend():
     got_row = kp2[:, write_page[0], int(lengths[0]) % ps]
     np.testing.assert_allclose(np.asarray(got_row), np.asarray(k_new[0].T).T,
                                atol=0, rtol=0)
+
+
+def test_stacked_page_write_touches_only_its_layer():
+    """The fused write + attend on stacked pools at ``layer`` equals the
+    4-D call on that layer's pools, and leaves every other layer's
+    pools as they were."""
+    b, hq, hkv, d, ps, t, n_layers, layer = 2, 4, 2, 32, 16, 3, 3, 1
+    q, _, _, bt, _ = _paged_fixture(b, hq, hkv, d, t, ps, seed=7)
+    n_pages = 1 + b * t
+    kst = _rand((n_layers, hkv, n_pages, ps, d), 21)
+    vst = _rand((n_layers, hkv, n_pages, ps, d), 22)
+    lengths = jnp.array([ps + 3, 2 * ps - 1], jnp.int32)
+    k_new = _rand((b, hkv, d), 11)
+    v_new = _rand((b, hkv, d), 12)
+    write_page = jnp.take_along_axis(bt, (lengths // ps)[:, None],
+                                     axis=1)[:, 0]
+    args = (bt, write_page, lengths % ps, lengths + 1)
+    out, kst2, vst2 = jax.jit(
+        lambda k, v, li: sharded_paged_decode_update_attend(
+            q, k_new, v_new, k, v, *args, page_size=ps, layer=li))(
+        kst, vst, jnp.int32(layer))
+    want, kp2, vp2 = sharded_paged_decode_update_attend(
+        q, k_new, v_new, kst[layer], vst[layer], *args, page_size=ps)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    for got, old, new in ((kst2, kst, kp2), (vst2, vst, vp2)):
+        np.testing.assert_array_equal(np.asarray(got[layer]),
+                                      np.asarray(new))
+        others = np.arange(n_layers) != layer
+        np.testing.assert_array_equal(np.asarray(got)[others],
+                                      np.asarray(old)[others])
 
 
 # ------------------------------------------------------- paged cache tree ----

@@ -222,6 +222,45 @@ def test_paged_engine_matches_dense_engine():
     assert outs[True] == outs[False]
 
 
+def test_in_place_pools_match_dense_greedy():
+    """The paged engine's decode scan carries its plain pools in place
+    and its step and admission consume them (donated).  Over decodes
+    that cross pages in both layers, its greedy tokens equal the
+    dense-cache engine's at the same seed."""
+    outs = {}
+    for paged in (False, True):
+        engine, *_ = _engine(slots=3, cache_len=48, max_new=20,
+                             paged=paged, page_size=4)
+        assert engine.metrics.gauge(
+            "serve.decode.pool_in_place").value == int(paged)
+        reqs = [Request(rid=i, tokens=[2 + i] * (5 + 3 * i))
+                for i in range(4)]
+        engine.run_to_completion(reqs)
+        assert all(r.done and len(r.out) == 20 for r in reqs)
+        outs[paged] = [r.out for r in reqs]
+    assert outs[True] == outs[False]
+
+
+@pytest.mark.parametrize("arch,kv_dtype,want", [
+    ("granite-8b", None, 1),          # one plain paged entry
+    ("granite-8b", "int8", 0),        # scale pools: per-layer slices
+    ("gemma2-2b", None, 1),           # its global layer; the window
+                                      # group's kw/vw entry falls back
+    ("gemma2-2b", "int8", 0),
+])
+def test_pool_in_place_gauge_counts_plain_paged_entries(arch, kv_dtype,
+                                                        want):
+    cfg = smoke_config(arch, num_layers=2)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    engine = Engine(model, params, ServeConfig(
+        slots=2, cache_len=32, paged=True, page_size=8, kv_dtype=kv_dtype))
+    assert engine.metrics.gauge(
+        "serve.decode.pool_in_place").value == want
+    assert engine.metrics.snapshot()["gauges"][
+        "serve.decode.pool_in_place"] == want
+
+
 def test_paged_pages_allocated_on_demand_and_freed():
     engine, *_ = _engine(slots=2, cache_len=32, max_new=8, paged=True,
                          page_size=8)

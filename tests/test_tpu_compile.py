@@ -13,8 +13,10 @@ imports this file.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import pathlib
+import re
 import shutil
 import subprocess
 import sys
@@ -202,6 +204,115 @@ def test_quant_scales_compile_at_thousands_of_pages(one_chip,
     with target("tpu", isa="v5e"):
         text = jax.jit(fn).lower(*args).compile().as_text()
     assert "portable_quant_paged_decode_attention" in compiled_kernels(text)
+
+
+# ------------------------------------------------- the engine's programs ----
+
+ENGINE_LAYERS, ENGINE_PAGES = 2, 520   # 136 MB a pool: more than VMEM
+
+
+@pytest.fixture(scope="module")
+def granite_engine():
+    """A paged ``Engine`` at granite-8b widths with 2 layers and 8 slots,
+    weights as shapes only; its pools are small, the lowered ones are
+    ``ENGINE_PAGES`` pages of 64."""
+    from repro.configs import get_config
+    from repro.models.registry import build_model
+    from repro.serve import Engine, ServeConfig
+    cfg = dataclasses.replace(get_config("granite-8b"),
+                              num_layers=ENGINE_LAYERS)
+    model = build_model(cfg)
+    params = jax.eval_shape(model._init_in_dtype, jax.random.PRNGKey(0))
+    return Engine(model, params, ServeConfig(
+        slots=B, cache_len=1024, total_pages=65, page_size=PS,
+        max_new_tokens=16, paged=True))
+
+
+def _aliased_params(text):
+    """Entry parameter numbers the executable aliases to an output."""
+    head = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    return {int(p) for p in re.findall(r"\}: \((\d+), \{",
+                                       head.group(1) if head else "")}
+
+
+def _pool_results(text, shapes):
+    """(opcode, root opcode of the computation a fusion calls) of every
+    instruction outside fused computations whose result is an array of
+    one of ``shapes``: what writes or moves a pool when it runs."""
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", text))
+    comp, root, found = None, {}, []
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            comp = head.group(1)
+            continue
+        ins = re.match(r"\s+(ROOT )?%[\w.\-]+ = (\w+\[[\d,]*\])\S* "
+                       r"([\w\-]+)\(", line)
+        if not ins:
+            continue
+        if ins.group(1):
+            root[comp] = ins.group(3)
+        if comp not in fused and ins.group(2) in shapes:
+            calls = re.search(r"calls=%([\w.\-]+)", line)
+            found.append((ins.group(3), calls and calls.group(1)))
+    return [(op, root.get(c)) for op, c in found]
+
+
+@pytest.mark.parametrize("program", ["step", "admit"])
+def test_engine_updates_the_pool_in_place(program, granite_engine,
+                                          one_chip, no_compile_cache):
+    """The engine's own jitted decode step and admission, compiled for
+    a v5e: each aliases its cache parameters to its cache outputs, and
+    no op but the pool update (a fusion rooted in a scatter) makes an
+    array of the stacked or one layer's pool shape, so nothing slices,
+    copies or writes back a pool.  The step still runs the paged kernel
+    through Mosaic."""
+    from repro.serve import paging
+    eng = granite_engine
+    sds = lambda tree: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip), tree)
+    caches = sds(jax.eval_shape(lambda: paging.init_paged_caches(
+        eng.model, B, eng.sc.cache_len, PS, ENGINE_PAGES)))
+    vec = lambda dt, *s: jax.ShapeDtypeStruct((B,) + s, dt,
+                                              sharding=one_chip)
+    hist = vec(i32, eng.sc.cache_len + 1)
+    params = sds(eng.params)
+    with target("tpu", isa="v5e"):
+        if program == "step":
+            first = len(jax.tree.leaves(params))
+            lowered = eng._step_fn.lower(
+                params, caches, vec(i32), vec(i32), vec(jnp.bool_),
+                vec(i32), jax.ShapeDtypeStruct((2,), jnp.uint32,
+                                               sharding=one_chip),
+                jax.ShapeDtypeStruct((), i32, sharding=one_chip), vec(i32),
+                vec(i32, eng.pages_per_slot), vec(jnp.bool_))
+        else:
+            first, k, plen = 0, 2, 128
+            cache1 = sds(jax.eval_shape(
+                lambda p: eng.model.prefill(
+                    p, jnp.zeros((k, plen), i32), eng.sc.cache_len, {})[1],
+                eng.params))
+            kv = lambda dt, *s: jax.ShapeDtypeStruct((k,) + s, dt,
+                                                     sharding=one_chip)
+            lowered = eng._admit_fn.lower(
+                caches, vec(i32), vec(i32), vec(jnp.bool_), vec(i32), hist,
+                vec(i32), cache1, kv(i32), kv(i32), kv(i32), kv(jnp.bool_),
+                kv(i32), kv(i32), kv(i32, eng.pages_per_slot),
+                kv(i32, eng.sc.cache_len + 1), None)
+        text = lowered.compile().as_text()
+    pools = jax.tree.leaves(caches)
+    assert _aliased_params(text) >= set(range(first, first + len(pools)))
+    pool = ",".join(map(str, pools[0].shape))
+    shapes = {f"bf16[{pool}]", f"bf16[{pool.split(',', 1)[1]}]"}
+    ops = _pool_results(text, shapes)
+    assert ops, "no pool-shaped op found: the parse missed the pools"
+    for op, fused_root in ops:
+        assert (op in ("parameter", "get-tuple-element", "bitcast")
+                or (op == "fusion" and fused_root == "scatter")), \
+            (op, fused_root)
+    assert sum(op == "fusion" for op, _ in ops) == len(pools)
+    if program == "step":
+        assert "portable_paged_decode_attention" in compiled_kernels(text)
 
 
 @pytest.mark.parametrize("where", ["checkout", "alone"])
